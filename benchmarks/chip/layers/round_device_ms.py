@@ -1,0 +1,9 @@
+"""Per round, the device-busy time inside the benchmark's ``run_round``
+span: the fused round program as the device runs it."""
+
+
+def read(run):
+    span_s, busy_s = run.trace.busy_in("run_round")
+    n = len([s for s in run.trace.spans if s[0] == "run_round"
+             and s[1] >= run.trace.window[0] and s[2] <= run.trace.window[1]])
+    return None if n == 0 or busy_s <= 0 else busy_s / n * 1e3
