@@ -219,24 +219,30 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
                 else stacked)
 
         # epochs advance in lockstep; the probe schedule is static, so the
-        # OPT transmission logic is only compiled at scheduled boundaries
+        # OPT transmission logic is only compiled at scheduled boundaries.
+        # The named scopes (local_epoch, probe, aggregate) label the
+        # compiled ops' op_name metadata and change no computation
         for e_t in range(1, local_epochs + 1):
-            stacked = epoch_all(stacked, xs[e_t - 1], ys[e_t - 1])
+            with jax.named_scope("local_epoch"):
+                stacked = epoch_all(stacked, xs[e_t - 1], ys[e_t - 1])
             if e_t in probe_epochs:
-                rate = chan["rates"][e_t - 1]
-                outage = chan["outages"][e_t - 1]
-                tau = chan["payload_bits"] / jnp.maximum(rate, 1e-9)  # eq. 15
-                ok, tau_extra = snapshot_decision(chan["valid"], outage,
-                                                  tau, tau_extra)
-                if use_codec:
-                    q_new, s_new = _codec_encode(stacked, params, interpret,
-                                                 codec_block, codec_bits)
-                    snap = (jnp.where(_kx(ok, q_new), q_new, snap[0]),
-                            jnp.where(_kx(ok, s_new), s_new, snap[1]))
-                else:
-                    snap = _tree_where_k(ok, stacked, snap)
-                has_snap = has_snap | ok
-                nsent = nsent + ok.astype(jnp.int32)
+                with jax.named_scope("probe"):
+                    rate = chan["rates"][e_t - 1]
+                    outage = chan["outages"][e_t - 1]
+                    # eq. 15
+                    tau = chan["payload_bits"] / jnp.maximum(rate, 1e-9)
+                    ok, tau_extra = snapshot_decision(chan["valid"], outage,
+                                                      tau, tau_extra)
+                    if use_codec:
+                        q_new, s_new = _codec_encode(stacked, params,
+                                                     interpret, codec_block,
+                                                     codec_bits)
+                        snap = (jnp.where(_kx(ok, q_new), q_new, snap[0]),
+                                jnp.where(_kx(ok, s_new), s_new, snap[1]))
+                    else:
+                        snap = _tree_where_k(ok, stacked, snap)
+                    has_snap = has_snap | ok
+                    nsent = nsent + ok.astype(jnp.int32)
         return stacked, snap, has_snap, nsent
 
     def _final_arrival(chan):
@@ -257,11 +263,12 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
         def round_fn(params, xs, ys, chan):
             stacked, snap, has_snap, nsent = _train_and_probe(
                 params, xs, ys, chan)
-            arrived = _final_arrival(chan)
-            new_params, rescued = _round_sync(params, stacked, snap,
-                                              has_snap, arrived)
-            delayed = scheme.delayed_out(chan["valid"], arrived)
-            dropped = chan["valid"] & ~arrived & ~rescued & ~delayed
+            with jax.named_scope("aggregate"):
+                arrived = _final_arrival(chan)
+                new_params, rescued = _round_sync(params, stacked, snap,
+                                                  has_snap, arrived)
+                delayed = scheme.delayed_out(chan["valid"], arrived)
+                dropped = chan["valid"] & ~arrived & ~rescued & ~delayed
             return new_params, RoundStats(arrived, rescued, delayed,
                                           dropped, nsent)
 
@@ -282,19 +289,20 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
                 f"build_fused_round needs k_carry >= the padded user bucket "
                 f"K (pass k_carry=k_select as HSFLSimulation does)")
         stacked, _, _, nsent = _train_and_probe(params, xs, ys, chan)
-        arrived = _final_arrival(chan)
-        delayed_new = scheme.delayed_out(chan["valid"], arrived)
-        new_params, rescued = scheme.aggregate(
-            params, stacked, None, None, arrived, delayed=delayed_stack,
-            delayed_mask=delayed_mask, async_weight=aw, k_carry=k_carry)
+        with jax.named_scope("aggregate"):
+            arrived = _final_arrival(chan)
+            delayed_new = scheme.delayed_out(chan["valid"], arrived)
+            new_params, rescued = scheme.aggregate(
+                params, stacked, None, None, arrived, delayed=delayed_stack,
+                delayed_mask=delayed_mask, async_weight=aw, k_carry=k_carry)
 
-        # next-round carry, padded to the fixed k_carry width
-        pad = k_carry - k
-        carry_stack = jax.tree_util.tree_map(
-            lambda s: jnp.pad(s, ((0, pad),) + ((0, 0),) * (s.ndim - 1)),
-            stacked)
-        carry_mask = jnp.pad(delayed_new, (0, pad))
-        dropped = chan["valid"] & ~arrived & ~rescued & ~delayed_new
+            # next-round carry, padded to the fixed k_carry width
+            pad = k_carry - k
+            carry_stack = jax.tree_util.tree_map(
+                lambda s: jnp.pad(s, ((0, pad),) + ((0, 0),) * (s.ndim - 1)),
+                stacked)
+            carry_mask = jnp.pad(delayed_new, (0, pad))
+            dropped = chan["valid"] & ~arrived & ~rescued & ~delayed_new
         return (new_params, carry_stack, carry_mask,
                 RoundStats(arrived, rescued, delayed_new, dropped, nsent))
 

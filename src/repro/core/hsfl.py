@@ -35,6 +35,7 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import latency as lat
 from repro.core.channel import ChannelParams, UAVFleet
@@ -303,7 +304,8 @@ class HSFLSimulation:
 
     def run_round(self, t: int, carry_delayed) -> Tuple[RoundLog, object]:
         if self.cfg.use_fused_round:
-            return self._run_round_fused(t, carry_delayed)
+            with TraceAnnotation("hsfl.round", round=t):
+                return self._run_round_fused(t, carry_delayed)
         return self._run_round_host(t, carry_delayed)
 
     # -- fused engine --------------------------------------------------------
@@ -367,8 +369,14 @@ class HSFLSimulation:
         return (stack, jax.device_put(np.zeros((k,), bool)))
 
     def _run_round_fused(self, t: int, carry_delayed):
+        """One round on the fused engine.  Its host stages run under the
+        profiler spans ``hsfl.schedule``, ``hsfl.presample``, ``hsfl.launch``
+        and ``hsfl.readback`` (inert unless a profiler trace is running), so
+        a trace can lay the device's idle gaps over them; a round with
+        nobody selected stops after scheduling."""
         cfg = self.cfg
-        sched, ue_bytes = self._schedule_round()
+        with TraceAnnotation("hsfl.schedule"):
+            sched, ue_bytes = self._schedule_round()
         log = RoundLog(round=t, selected=len(sched))
         if isinstance(carry_delayed, (list, tuple)) and not carry_delayed:
             carry_delayed = None
@@ -388,52 +396,59 @@ class HSFLSimulation:
             # sharded user axis must stay divisible by the device count
             # (k_select is — the __init__ guard — so this stays ≤ k_select)
             K = -(-K // self._shard_ndev) * self._shard_ndev
-        xs, ys, rates, outs, final_rate, final_out = \
-            self._presample_round(sched, K)
-        payload, tau_extra0, train_time, valid = \
-            self._user_consts(sched, ue_bytes, K)
+        with TraceAnnotation("hsfl.presample"):
+            xs, ys, rates, outs, final_rate, final_out = \
+                self._presample_round(sched, K)
+            payload, tau_extra0, train_time, valid = \
+                self._user_consts(sched, ue_bytes, K)
+            # dtype conversions happen host-side; a single explicit
+            # device_put per input stages the round, so the loop runs clean
+            # under jax.transfer_guard_host_to_device("disallow")
+            chan = {
+                "rates": np.asarray(rates), "outages": np.asarray(outs),
+                "payload_bits": np.asarray(payload * 8.0, np.float32),
+                "tau_extra0": np.asarray(tau_extra0, np.float32),
+                "final_rate": np.asarray(final_rate),
+                "final_outage": np.asarray(final_out),
+                "train_time": np.asarray(train_time, np.float32),
+                "valid": np.asarray(valid),
+            }
+            staged = xs.nbytes + ys.nbytes + sum(a.nbytes
+                                                 for a in chan.values())
 
-        # dtype conversions happen host-side; a single explicit device_put
-        # per input stages the round, so the loop runs clean under
-        # jax.transfer_guard_host_to_device("disallow")
-        xs = jax.device_put(xs, self._batch_shard)
-        ys = jax.device_put(ys, self._batch_shard)
-        chan = jax.device_put({
-            "rates": np.asarray(rates), "outages": np.asarray(outs),
-            "payload_bits": np.asarray(payload * 8.0, np.float32),
-            "tau_extra0": np.asarray(tau_extra0, np.float32),
-            "final_rate": np.asarray(final_rate),
-            "final_outage": np.asarray(final_out),
-            "train_time": np.asarray(train_time, np.float32),
-            "valid": np.asarray(valid),
-        })
+        with TraceAnnotation("hsfl.launch", users=len(sched), K=K,
+                             staged_bytes=staged):
+            xs = jax.device_put(xs, self._batch_shard)
+            ys = jax.device_put(ys, self._batch_shard)
+            chan = jax.device_put(chan)
+            if self.scheme.carries_delayed:
+                stack, mask = (carry_delayed if carry_delayed is not None
+                               else self._empty_carry())
+                self.params, c_stack, c_mask, stats = self._fused(
+                    self.params, stack, mask, xs, ys, chan)
+                new_carry = (c_stack, c_mask)
+            else:
+                self.params, stats = self._fused(self.params, xs, ys, chan)
+                new_carry = None
 
-        if self.scheme.carries_delayed:
-            stack, mask = (carry_delayed if carry_delayed is not None
-                           else self._empty_carry())
-            self.params, c_stack, c_mask, stats = self._fused(
-                self.params, stack, mask, xs, ys, chan)
-            new_carry = (c_stack, c_mask)
-        else:
-            self.params, stats = self._fused(self.params, xs, ys, chan)
-            new_carry = None
-
-        arrived = np.asarray(stats.arrived)
-        rescued = np.asarray(stats.rescued)
-        delayed = np.asarray(stats.delayed)
-        dropped = np.asarray(stats.dropped)
-        sends = np.asarray(stats.opp_sends)
-        log.arrived_final = int(arrived.sum())
-        log.used_snapshot = int(rescued.sum())
-        log.delayed = int(delayed.sum())
-        log.dropped = int(dropped.sum())
-        events = sends + arrived.astype(np.int64)
-        log.bytes_sent = float(np.sum(payload * events))
-        for j, u in enumerate(sched):
-            if u.mode == "SL" and events[j] > 0:
-                # one-off activation payload m_a rides the SL uplink (eq. 12)
-                wl = self.workloads[u.index]
-                log.bytes_sent += wl.act_bytes_per_sample * wl.samples
+        # the host waits here for the round program
+        with TraceAnnotation("hsfl.readback"):
+            arrived = np.asarray(stats.arrived)
+            rescued = np.asarray(stats.rescued)
+            delayed = np.asarray(stats.delayed)
+            dropped = np.asarray(stats.dropped)
+            sends = np.asarray(stats.opp_sends)
+            log.arrived_final = int(arrived.sum())
+            log.used_snapshot = int(rescued.sum())
+            log.delayed = int(delayed.sum())
+            log.dropped = int(dropped.sum())
+            events = sends + arrived.astype(np.int64)
+            log.bytes_sent = float(np.sum(payload * events))
+            for j, u in enumerate(sched):
+                if u.mode == "SL" and events[j] > 0:
+                    # one-off activation payload m_a rides the SL uplink (eq. 12)
+                    wl = self.workloads[u.index]
+                    log.bytes_sent += wl.act_bytes_per_sample * wl.samples
         return log, new_carry
 
     # -- host reference engine ----------------------------------------------
