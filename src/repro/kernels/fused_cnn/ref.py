@@ -16,6 +16,9 @@ backward.  This module is the algorithmic reference the Pallas kernels
   1/count semantics, so grads match ``jax.grad`` of the reference
   exactly) and the ReLU mask — the backward is pure mask algebra plus the
   two transposed matmuls, never re-deriving activations.
+- **input gradient** (conv2's ``dx``, which conv1's backward needs): the
+  patch cotangent folds back with ``fold3x3``, a sum of nine shifted
+  slices of it, which XLA fuses into one pass (no scatter-add).
 - conv1's ``dx`` (the fold back to the input image) is exposed but unused
   by the training step — images carry no gradient, XLA DCEs it.
 
@@ -47,15 +50,26 @@ def patches3x3(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def fold3x3(dpatches: jnp.ndarray) -> jnp.ndarray:
-    """Transpose of ``patches3x3``: scatter-add (B,H,W,9C) -> (B,H,W,C)."""
-    b, h, w, c9 = dpatches.shape
+    """Transpose of ``patches3x3``: fold (B,H,W,9C) -> (B,H,W,C).
+
+    Written as a gather: pad the patch cotangent by 1 on each side of H
+    and W, then add nine static slices in tap order t = 3i + j, the slice
+    of tap t shifted by (2 - i, 2 - j).  Each element sums the same terms
+    in the same order as a scatter-add of the taps into a zero buffer
+    (a tap that misses the element adds an exact 0.0), so the values are
+    the same bit for bit wherever each add rounds (f32; bf16 on the CPU,
+    while a TPU fusion keeps a bf16 sum in f32 and rounds once).  XLA
+    fuses the gather into one elementwise pass, where it runs a
+    scatter-add as nine padded ``dynamic-update-slice`` ops on a TPU."""
+    _, h, w, c9 = dpatches.shape
     c = c9 // 9
-    dxp = jnp.zeros((b, h + 2, w + 2, c), dpatches.dtype)
-    for idx in range(9):
-        i, j = divmod(idx, 3)
-        dxp = dxp.at[:, i:i + h, j:j + w, :].add(
-            dpatches[..., idx * c:(idx + 1) * c])
-    return dxp[:, 1:1 + h, 1:1 + w, :]
+    dp = jnp.pad(dpatches, ((0, 0), (1, 1), (1, 1), (0, 0)))
+    dx = None
+    for t in range(9):
+        i, j = divmod(t, 3)
+        tap = dp[:, 2 - i:2 - i + h, 2 - j:2 - j + w, t * c:(t + 1) * c]
+        dx = tap if dx is None else dx + tap
+    return dx
 
 
 def conv_pool_fwd(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray

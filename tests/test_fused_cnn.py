@@ -456,3 +456,54 @@ def test_stacked_epoch_donates_stacked_carry():
         "donated stacked buffer was not aliased to the output"
     assert out["fc1"]["w"].shape == (4,) + tuple(cnn_mod.init_cnn(
         jax.random.PRNGKey(0))["fc1"]["w"].shape)
+
+
+# -- the fold of the patch cotangent (conv backward's input gradient) ---------
+
+def _fold3x3_scatter_add(dpatches):
+    """The scatter-add form ``ref.fold3x3`` replaced: each tap added into
+    a zero (B,H+2,W+2,C) buffer, then the border cropped."""
+    b, h, w, c9 = dpatches.shape
+    c = c9 // 9
+    dxp = jnp.zeros((b, h + 2, w + 2, c), dpatches.dtype)
+    for t in range(9):
+        i, j = divmod(t, 3)
+        dxp = dxp.at[:, i:i + h, j:j + w, :].add(
+            dpatches[..., t * c:(t + 1) * c])
+    return dxp[:, 1:1 + h, 1:1 + w, :]
+
+
+FOLD_SHAPES = {"conv2_k10": (10 * 10, 14, 14, 72),   # K·B users' conv2
+               "conv2_k3": (3 * 10, 14, 14, 72),
+               "conv1": (10, 28, 28, 9)}
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+@pytest.mark.parametrize("shape", list(FOLD_SHAPES), ids=str)
+def test_fold3x3_is_the_scatter_add_bitwise(shape, precision):
+    """The gather adds the same terms in the same order.  (In bf16 this
+    holds where each add rounds, as on the CPU; a TPU fusion keeps the
+    bf16 sum in f32 and rounds once.)"""
+    from repro.kernels.fused_cnn.ref import fold3x3
+    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16}[precision]
+    g = jax.random.normal(jax.random.PRNGKey(5), FOLD_SHAPES[shape]).astype(dt)
+    got = np.asarray(jax.jit(fold3x3)(g))
+    want = np.asarray(jax.jit(_fold3x3_scatter_add)(g))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    bits = np.uint16 if precision == "bf16" else np.uint32
+    np.testing.assert_array_equal(got.view(bits), want.view(bits))
+
+
+@pytest.mark.parametrize("shape", [(4, 14, 14, 8), (3, 28, 28, 1)], ids=str)
+def test_fold3x3_is_the_transpose_of_patches3x3(shape):
+    """<patches3x3(x), g> == <x, fold3x3(g)>: the fold is the adjoint."""
+    from repro.kernels.fused_cnn.ref import fold3x3, patches3x3
+    kx, kg = jax.random.split(jax.random.PRNGKey(7))
+    x = jax.random.normal(kx, shape)
+    g = jax.random.normal(kg, shape[:-1] + (9 * shape[-1],))
+    lhs = np.vdot(np.asarray(patches3x3(x), np.float64),
+                  np.asarray(g, np.float64))
+    rhs = np.vdot(np.asarray(x, np.float64),
+                  np.asarray(fold3x3(g), np.float64))
+    scale = np.abs(np.asarray(g, np.float64)).sum()
+    assert abs(lhs - rhs) <= 1e-6 * scale, (lhs, rhs)

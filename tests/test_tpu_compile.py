@@ -17,6 +17,7 @@ the compiles: an entry written for a described chip cannot be read back
 without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +86,31 @@ def test_stacked_epoch_compiles(one_chip, kernel, precision):
     # per SGD step: conv1/conv2 forward, the fc chain both ways, and the
     # conv2 and conv1 backward — six kernels in the scanned step
     assert hlo.count("tpu_custom_call") == (6 if kernel == "pallas" else 0)
+
+
+def _top_level_lines(hlo: str):
+    """Instruction lines of every computation that is not a fusion's body:
+    the ops the chip runs one by one."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    name = None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+        elif name not in fused:
+            yield line
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_stacked_epoch_folds_without_scatter_add(one_chip, precision):
+    """conv2's input gradient folds the patch cotangent as one fusion: no
+    op of the compiled epoch is a ``dynamic-update-slice`` from a
+    scatter-add (the form that ran as nine padded ops per step)."""
+    hlo = _compile(make_stacked_epoch_fn(ForwardPolicy(precision=precision),
+                                         0.01), *_epoch_args(one_chip))
+    dus = [l for l in _top_level_lines(hlo)
+           if "dynamic-update-slice(" in l and "scatter-add" in l]
+    assert not dus, dus[:2]
 
 
 def test_quantize_blocks_compiles(one_chip):
