@@ -45,6 +45,23 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_d_ff: int = 0              # per-expert FFN width (granite: 512)
     router_aux_coef: float = 0.01  # load-balance loss coefficient
+    # DeepSeek-V3 routing (moonlight): sigmoid scores, top-k of score +
+    # correction bias, chosen scores normalised and scaled; shared experts
+    # of width n_shared_experts * moe_d_ff; the first first_k_dense layers
+    # keep the dense SwiGLU of width d_ff
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    first_k_dense: int = 0
+    # expert parallelism: this chip holds routed experts
+    # [expert_offset, expert_offset + experts_held) of num_experts (0 = all)
+    experts_held: int = 0
+    expert_offset: int = 0
+    # multi-head latent attention (kv_lora_rank > 0): q = W_q x with
+    # head_dim (nope) + qk_rope_head_dim per head, one shared RoPE key, a
+    # kv_lora_rank latent expanded to head_dim keys and v_head_dim values
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # ssm / hybrid
     ssm_state: int = 0             # mamba state size N (hymba: 16)
     ssm_expand: int = 2            # mamba inner expansion
@@ -87,6 +104,40 @@ class ModelConfig:
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+    def cut(self, *, layers: int = 0, experts_held: int = 0,
+            expert_offset: int = 0, vocab: int = 0) -> "ModelConfig":
+        """One chip's share of the model: ``layers`` layers in all
+        (leading dense ones included), the routed experts
+        ``[expert_offset, expert_offset + experts_held)`` and the first
+        ``vocab`` rows of the vocabulary; 0 keeps the model's own.  Every
+        width, the router's outputs and its experts per token stay as they
+        are."""
+        if vocab > self.vocab_size:
+            raise ValueError(f"{self.name}: a cut keeps at most the "
+                             f"{self.vocab_size} rows of the vocabulary")
+        return self.replace(
+            num_layers=layers or self.num_layers,
+            experts_held=experts_held or self.experts_held
+            or self.num_experts,
+            expert_offset=expert_offset,
+            vocab_size=vocab or self.vocab_size)
+
+    def check_source(self, keys: dict) -> "ModelConfig":
+        """The model, once each of its source's config.json ``keys``
+        (names as ``SOURCE_KEYS`` maps them) is found to hold the model's
+        own value; raises on the first that differs.  Keys the layers do
+        not implement must hold the value the layers assume."""
+        for k, v in keys.items():
+            field = SOURCE_KEYS[k]
+            if field is None:
+                continue
+            want = field[1] if isinstance(field, tuple) \
+                else getattr(self, field)
+            if v != want:
+                raise ValueError(f"{self.name}: the source's {k}={v!r}, "
+                                 f"the model's {want!r}")
+        return self
 
     def with_sliding_window(self, window: int = 8192) -> "ModelConfig":
         """First-class long-context variant for dense archs (DESIGN.md §5)."""
@@ -156,6 +207,38 @@ class ModelConfig:
         )
 
 
+# config.json keys of a DeepSeek-V3-style source and the ModelConfig field
+# each must equal; (None, value) where the layers implement that value
+# alone (grouped routing, multi-token prediction, MoE every other layer),
+# None for the checkpoint's own settings the layers do not read.  A
+# sliced vocabulary is a cut (``ModelConfig.cut``), not a source key
+SOURCE_KEYS = {
+    "num_hidden_layers": "num_layers",
+    "hidden_size": "d_model",
+    "intermediate_size": "d_ff",
+    "moe_intermediate_size": "moe_d_ff",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "qk_nope_head_dim": "head_dim",
+    "qk_rope_head_dim": "qk_rope_head_dim",
+    "v_head_dim": "v_head_dim",
+    "kv_lora_rank": "kv_lora_rank",
+    "n_routed_experts": "num_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "n_shared_experts": "n_shared_experts",
+    "routed_scaling_factor": "routed_scaling_factor",
+    "first_k_dense_replace": "first_k_dense",
+    "rms_norm_eps": "norm_eps",
+    "rope_theta": "rope_theta",
+    "n_group": (None, 1),
+    "topk_group": (None, 1),
+    "moe_layer_freq": (None, 1),
+    "num_nextn_predict_layers": (None, 0),
+    "max_position_embeddings": None,
+    "ep_size": None,
+}
+
+
 ARCH_IDS = (
     "hymba-1.5b",
     "deepseek-67b",
@@ -181,6 +264,7 @@ _MODULE_FOR = {
     "granite-moe-3b-a800m": "granite_moe_3b",
     "hubert-xlarge": "hubert_xlarge",
     "paper-cnn": "paper_cnn",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

@@ -42,7 +42,10 @@ Two round builders share these pieces:
   compiled only at scheduled epoch boundaries; everything data-dependent
   (outages, τ budget, arrival, rescue, staleness) stays branch-free
   on-device.  This path replays the host numpy RNG streams bit-for-bit
-  (the fused-vs-host equivalence contract).
+  (the fused-vs-host equivalence contract).  Its streamed form
+  (``streamed=True``, picked by ``streams_users`` where K stacked copies
+  of the model would not fit the device) takes the same inputs and trains
+  one user at a time, folding each into a running sum.
 - ``build_device_round`` — the whole control plane on-device: channel/
   mobility from a ``channel_lib.FleetState`` carry, greedy selection via
   ``selection.select_users_jax``, batches gathered in-program, epochs
@@ -54,6 +57,7 @@ Two round builders share these pieces:
 """
 from __future__ import annotations
 
+import os
 from dataclasses import replace as _dc_replace
 from typing import Any, Callable, Dict, NamedTuple, Tuple
 
@@ -164,13 +168,44 @@ def _make_epoch_fn(loss_grad: Callable, lr: float) -> Callable:
     return epoch_fn
 
 
+def device_memory_bytes(device=None) -> int:
+    """Memory of ``device`` (the default device): the allocator's limit
+    where the backend reports one, else the host's physical memory."""
+    device = device or jax.devices()[0]
+    stats = device.memory_stats() or {}
+    if "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    return os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
+
+
+# per user, the stacked round holds the trained weights, the snapshot and
+# the gradient of one step
+STACKED_COPIES = 3
+
+
+def streams_users(params, k_select: int, device=None) -> bool:
+    """Whether the round streams its users through one program (True) or
+    stacks them over K: stacked, ``STACKED_COPIES`` copies of the parameter
+    tree per user would take more than half the device's memory."""
+    tree = sum(a.size * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(params))
+    return STACKED_COPIES * k_select * tree > device_memory_bytes(device) / 2
+
+
+def sgd(params, grads, lr: float):
+    """Plain SGD in the master weights' dtype."""
+    return jax.tree_util.tree_map(lambda w, g: w - lr * g, params, grads)
+
+
 def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
                       lr: float, tau_max: float, probe_epochs: Tuple[int, ...],
                       async_weight: float = 0.0, use_codec: bool = False,
                       interpret: bool = False, k_carry: int = 0,
                       forward: Any = None, codec_block: int = BLOCK,
                       codec_bits: int = 8,
-                      stacked_sharding: Any = None) -> Callable:
+                      stacked_sharding: Any = None, streamed: bool = False,
+                      client_step: Callable | None = None,
+                      load0: Any = None) -> Callable:
     """Compile one HSFL round for a fixed (scheme, e, steps, schedule).
 
     Returns ``round_fn(params, xs, ys, chan)`` for opt/discard, or
@@ -193,9 +228,28 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
     parameter state every dispatch — do not reuse those arrays after the
     call.  ``codec_block``/``codec_bits`` are the delta-codec quantization
     group width and bit depth (``HSFLConfig.codec_block``/``codec_bits``).
+
+    ``streamed`` builds the streamed form (``streams_users`` decides when):
+    ``round_fn(params, xs, ys, chan) -> (new_params, stats, load)``, which
+    trains one user at a time and folds each into a running sum, so that
+    the round holds a few copies of the model whatever K is.
+    ``client_step(params, x, y) -> (loss, grads, load)`` is one user's
+    training step there (the model's own; by default the ``forward``
+    policy's step with an empty ``load``), and ``load0`` the zero of the
+    ``load`` it returns; the round sums it over each local epoch's steps
+    and returns it per user slot and epoch, with leading axes (K, e).
     """
-    epoch_all, _ = _resolve_epoch_fns(forward, lr, interpret)
     scheme = get_scheme(scheme)
+    if streamed:
+        if client_step is None:
+            loss_grad, _ = resolve_train_step(forward, interpret)
+            client_step, load0 = (lambda p, x, y: (*loss_grad(p, x, y), {}),
+                                  {})
+        return _build_streamed_round(
+            scheme=scheme, local_epochs=local_epochs, lr=lr,
+            tau_max=tau_max, probe_epochs=probe_epochs, use_codec=use_codec,
+            client_step=client_step, load0=load0)
+    epoch_all, _ = _resolve_epoch_fns(forward, lr, interpret)
 
     if scheme.carries_delayed and k_carry < 1:
         raise ValueError(
@@ -245,12 +299,6 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
                     nsent = nsent + ok.astype(jnp.int32)
         return stacked, snap, has_snap, nsent
 
-    def _final_arrival(chan):
-        tau_f = chan["payload_bits"] / jnp.maximum(chan["final_rate"], 1e-9)
-        fits = chan["train_time"] + scheme.final_slack(chan["tau_extra0"]) \
-            + tau_f <= tau_max
-        return chan["valid"] & (~chan["final_outage"]) & fits
-
     def _round_sync(params, stacked, snap, has_snap, arrived):
         """Scheme aggregation: masked mean over finals (+ rescues)."""
         if scheme.uses_probes and use_codec:
@@ -264,7 +312,7 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
             stacked, snap, has_snap, nsent = _train_and_probe(
                 params, xs, ys, chan)
             with jax.named_scope("aggregate"):
-                arrived = _final_arrival(chan)
+                arrived = _final_arrival(scheme, chan, tau_max)
                 new_params, rescued = _round_sync(params, stacked, snap,
                                                   has_snap, arrived)
                 delayed = scheme.delayed_out(chan["valid"], arrived)
@@ -290,7 +338,7 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
                 f"K (pass k_carry=k_select as HSFLSimulation does)")
         stacked, _, _, nsent = _train_and_probe(params, xs, ys, chan)
         with jax.named_scope("aggregate"):
-            arrived = _final_arrival(chan)
+            arrived = _final_arrival(scheme, chan, tau_max)
             delayed_new = scheme.delayed_out(chan["valid"], arrived)
             new_params, rescued = scheme.aggregate(
                 params, stacked, None, None, arrived, delayed=delayed_stack,
@@ -310,6 +358,116 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
     # the async chain stops copying the full per-user parameter stack
     # every round
     return jax.jit(round_fn, donate_argnums=(0, 1, 2))
+
+
+def _final_arrival(scheme, chan, tau_max: float):
+    """Alg. 2 l. 14: the final upload arrives if the link is up and
+    training, the scheme's slack and the upload fit tau_max.  It reads
+    only the presampled inputs, so it is known before training."""
+    tau_f = chan["payload_bits"] / jnp.maximum(chan["final_rate"], 1e-9)
+    fits = chan["train_time"] + scheme.final_slack(chan["tau_extra0"]) \
+        + tau_f <= tau_max
+    return chan["valid"] & (~chan["final_outage"]) & fits
+
+
+def _build_streamed_round(*, scheme, local_epochs: int, lr: float,
+                          tau_max: float, probe_epochs: Tuple[int, ...],
+                          use_codec: bool, client_step: Callable,
+                          load0: Any) -> Callable:
+    """One round that trains the selected users one after another.
+
+    Every decision of the round reads presampled inputs only: the probes'
+    outcomes (``snapshot_decision`` over all users at each scheduled
+    epoch, eqs. 14-16) and the final arrival are taken before training.
+    So each user's contribution is known to be its final weights (the
+    final arrives), its last snapshot (the final is lost, a probe went
+    out) or nothing, and the epoch it is taken at is known too.  A
+    ``fori_loop`` over the selected users (the first ``sum(valid)`` slots;
+    a dynamic trip count, so one compile serves every user count and no
+    padded user trains) runs each user's epochs from the global weights
+    and, at that epoch, folds the weights into a running sum with the
+    weight the scheme's per-user ``pod_contribution`` gives them; no
+    snapshot is copied.  The round ends with the sum over its weight, or
+    the global weights where nothing arrived."""
+    if scheme.carries_delayed or not scheme.per_user_fold:
+        raise ValueError(
+            f"scheme {scheme.name!r} aggregates all K users at once, which "
+            f"the streamed round (one user at a time) cannot do; it streams "
+            f"only schemes whose aggregate is a per-user weighted mean")
+    if use_codec:
+        raise ValueError("the streamed round carries no delta-codec state")
+
+    def round_fn(params, xs, ys, chan):
+        valid = chan["valid"]
+        k = valid.shape[0]
+        arrived = _final_arrival(scheme, chan, tau_max)
+        tau_extra = chan["tau_extra0"]
+        has_snap = jnp.zeros((k,), bool)
+        nsent = jnp.zeros((k,), jnp.int32)
+        snap_epoch = jnp.full((k,), -1, jnp.int32)
+        with jax.named_scope("probe"):
+            for e_t in probe_epochs:
+                tau = chan["payload_bits"] / jnp.maximum(
+                    chan["rates"][e_t - 1], 1e-9)                 # eq. 15
+                ok, tau_extra = snapshot_decision(
+                    valid, chan["outages"][e_t - 1], tau, tau_extra)
+                snap_epoch = jnp.where(ok, e_t - 1, snap_epoch)
+                has_snap = has_snap | ok
+                nsent = nsent + ok.astype(jnp.int32)
+        # the epoch after which each user's contribution is taken
+        fold_epoch = jnp.where(arrived, local_epochs - 1, snap_epoch)
+
+        def user(u, carry):
+            def epoch(c, inp):
+                p, acc, wsum = c
+                x, y, e_i = inp
+
+                def step(c, batch):
+                    p, load = c
+                    _, g, used = client_step(p, *batch)
+                    return (sgd(p, g, lr), jax.tree_util.tree_map(
+                        jnp.add, load, used)), ()
+
+                with jax.named_scope("local_epoch"):
+                    (p, load), _ = jax.lax.scan(step, (p, load0), (x, y))
+                with jax.named_scope("aggregate"):
+                    contrib, w = scheme.pod_contribution(
+                        p, p, has_snap[u], arrived[u])
+                    now = e_i == fold_epoch[u]
+                    acc = jax.lax.cond(
+                        now, lambda a: jax.tree_util.tree_map(
+                            lambda s, c: s + w * c, a, contrib),
+                        lambda a: a, acc)
+                return (p, acc, wsum + jnp.where(now, w, 0.0)), load
+
+            acc, wsum, loads = carry
+            xu = jax.lax.dynamic_index_in_dim(xs, u, 1, keepdims=False)
+            yu = jax.lax.dynamic_index_in_dim(ys, u, 1, keepdims=False)
+            (_, acc, wsum), load = jax.lax.scan(
+                epoch, (params, acc, wsum),
+                (xu, yu, jnp.arange(local_epochs)))
+            loads = jax.tree_util.tree_map(
+                lambda a, v: jax.lax.dynamic_update_index_in_dim(a, v, u, 0),
+                loads, load)
+            return acc, wsum, loads
+
+        zero = jax.tree_util.tree_map(jnp.zeros_like, params)
+        loads = jax.tree_util.tree_map(
+            lambda z: jnp.zeros((k, local_epochs) + z.shape, z.dtype), load0)
+        acc, wsum, load = jax.lax.fori_loop(
+            0, jnp.sum(valid.astype(jnp.int32)), user,
+            (zero, jnp.zeros((), jnp.float32), loads))
+        with jax.named_scope("aggregate"):
+            denom = jnp.where(wsum > 0, wsum, 1.0)
+            new_params = jax.tree_util.tree_map(
+                lambda a, p: jnp.where(wsum > 0, a / denom, p), acc, params)
+            rescued = ~arrived & has_snap
+            delayed = scheme.delayed_out(valid, arrived)
+            dropped = valid & ~arrived & ~rescued & ~delayed
+        return new_params, RoundStats(arrived, rescued, delayed, dropped,
+                                      nsent), load
+
+    return jax.jit(round_fn, donate_argnums=(0,))
 
 
 # ---------------------------------------------------------------------------

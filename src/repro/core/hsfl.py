@@ -29,8 +29,8 @@ Two round engines share the control plane:
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Dict, List, Tuple
+from dataclasses import dataclass, field, fields
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,19 +39,53 @@ from jax.profiler import TraceAnnotation
 
 from repro.core import latency as lat
 from repro.core.channel import ChannelParams, UAVFleet
-from repro.core.fused_round import build_fused_round
+from repro.core.fused_round import build_fused_round, streams_users
 from repro.core.metrics import RoundLog, SimLog
 from repro.core.schemes import get_scheme
 from repro.core.transmission import OppTransmitter
-from repro.data.synthetic import Dataset, make_digits
+from repro.data.synthetic import Dataset, make_digits, make_token_shards
 from repro.data.partition import partition
 from repro.kernels.delta_codec.ops import codec_ratio, decode_delta, encode_delta
 from repro.models import cnn as cnn_mod
+from repro.models.registry import client_model
 from repro.training.loss import accuracy, cross_entropy
 
 
 @dataclass
-class HSFLConfig:
+class SourceKeys:
+    """A language model's config.json keys, as a configuration file copies
+    them from the model's source (``configs.base.SOURCE_KEYS``).  They
+    record the source: each one set must equal the registry's value, and
+    ``vocab_size`` is the rows of the vocabulary the cut keeps.  The CNN
+    reads none of them."""
+    num_hidden_layers: Optional[int] = None
+    hidden_size: Optional[int] = None
+    intermediate_size: Optional[int] = None
+    moe_intermediate_size: Optional[int] = None
+    num_attention_heads: Optional[int] = None
+    num_key_value_heads: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    n_routed_experts: Optional[int] = None
+    num_experts_per_tok: Optional[int] = None
+    n_shared_experts: Optional[int] = None
+    routed_scaling_factor: Optional[float] = None
+    first_k_dense_replace: Optional[int] = None
+    rms_norm_eps: Optional[float] = None
+    rope_theta: Optional[float] = None
+    vocab_size: Optional[int] = None
+    n_group: Optional[int] = None
+    topk_group: Optional[int] = None
+    moe_layer_freq: Optional[int] = None
+    num_nextn_predict_layers: Optional[int] = None
+    max_position_embeddings: Optional[int] = None
+    ep_size: Optional[int] = None
+
+
+@dataclass
+class HSFLConfig(SourceKeys):
     scheme: str = "opt"            # opt | discard | async
     distribution: str = "noniid"   # iid | noniid | imbalanced
     n_uavs: int = 30
@@ -106,24 +140,72 @@ class HSFLConfig:
     channel: ChannelParams = field(default_factory=ChannelParams)
     async_alpha: float = 0.4
     async_a: float = 0.5
+    # the model each UAV trains: "paper-cnn" on the digits, or a language
+    # model of ``repro.configs`` on non-iid token shards (each sample one
+    # sequence of seq_len tokens; a client's samples index a pool of
+    # token_pool sequences of its own stream; eval_seqs held-out ones).
+    # The cut is the chip's share of the model (``ModelConfig.cut``):
+    # layers, the routed experts held from expert_offset and the first
+    # ``vocab_size`` rows of the vocabulary (0 or None keeps the model's)
+    model: str = "paper-cnn"
+    layers: int = 0
+    experts_held: int = 0
+    expert_offset: int = 0
+    seq_len: int = 2048
+    token_pool: int = 8
+    eval_seqs: int = 8
+
+    @property
+    def token_model(self) -> bool:
+        return self.model != "paper-cnn"
+
+    def model_config(self):
+        """The cut ``ModelConfig`` of a token model."""
+        from repro.configs import get_config
+        keys = {f.name: getattr(self, f.name) for f in fields(SourceKeys)
+                if f.name != "vocab_size" and getattr(self, f.name)
+                is not None}
+        mcfg = get_config(self.model).check_source(keys).cut(
+            layers=self.layers, experts_held=self.experts_held,
+            expert_offset=self.expert_offset, vocab=self.vocab_size or 0)
+        if self.seq_len > (self.max_position_embeddings or self.seq_len):
+            raise ValueError(f"seq_len {self.seq_len} exceeds the model's "
+                             f"{self.max_position_embeddings} positions")
+        return mcfg
+
+
+def init_params(cfg: HSFLConfig, key):
+    """The configured model's initial weights (the trainable tree only)."""
+    if cfg.token_model:
+        return client_model(cfg.model_config()).init(key)
+    return cnn_mod.init_cnn(key)
 
 
 def model_compress_ratio(cfg: HSFLConfig) -> float:
     """The effective snapshot compression ratio for ``cfg``.
 
     With ``use_delta_codec`` the knob is *derived* — the actual int8+scale
-    byte count of this config's CNN over its float32 bytes
-    (``delta_codec.ops.codec_ratio``), computed from abstract shapes so no
-    params are materialized; otherwise it is the hand-set
+    byte count of the configured model's parameter tree over its float32
+    bytes (``delta_codec.ops.codec_ratio``), computed from abstract shapes
+    so no params are materialized; otherwise it is the hand-set
     ``cfg.compress_ratio``.  Shared by ``HSFLSimulation`` (host/fused
     engines) and ``core/sweep`` (device engine) so the eq. 14/15 payload
     accounting cannot drift between them."""
     if not cfg.use_delta_codec:
         return cfg.compress_ratio
-    shapes = jax.eval_shape(lambda: cnn_mod.init_cnn(jax.random.PRNGKey(0)))
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     n = sum(int(np.prod(l.shape))
             for l in jax.tree_util.tree_leaves(shapes))
     return codec_ratio(n, cfg.codec_block, cfg.codec_bits)
+
+
+def shard_sizes(cfg: HSFLConfig) -> List[int]:
+    """Samples per UAV of the digits' split without making the digits: the
+    labels are the first draw of ``make_digits``'s stream."""
+    n = cfg.n_train
+    y = np.random.default_rng(cfg.seed).integers(0, 10, n + cfg.n_test)[:n]
+    return [len(c) for c in partition(Dataset(np.arange(n), y), cfg.n_uavs,
+                                      cfg.distribution, cfg.seed)]
 
 
 def _heterogeneous_devices(n: int, rng: np.random.Generator,
@@ -200,17 +282,35 @@ class HSFLSimulation:
         # final deadline and aggregation all dispatch through it
         self.scheme = get_scheme(cfg.scheme)
         self.rng = np.random.default_rng(cfg.seed)
-        full = make_digits(cfg.n_train + cfg.n_test, seed=cfg.seed)
-        self.test = Dataset(full.x[cfg.n_train:], full.y[cfg.n_train:])
-        train = Dataset(full.x[:cfg.n_train], full.y[:cfg.n_train])
-        self.clients = partition(train, cfg.n_uavs, cfg.distribution, cfg.seed)
+        if cfg.token_model:
+            # the digits' sample counts, so the control plane is Sec. IV's
+            if not cfg.use_fused_round:
+                raise ValueError("token models train on the fused round "
+                                 "only (use_fused_round=True)")
+            self.clients, self.test = make_token_shards(
+                shard_sizes(cfg), cfg.token_pool, cfg.seq_len,
+                cfg.model_config().vocab_size, cfg.seed, cfg.eval_seqs)
+        else:
+            full = make_digits(cfg.n_train + cfg.n_test, seed=cfg.seed)
+            self.test = Dataset(full.x[cfg.n_train:], full.y[cfg.n_train:])
+            train = Dataset(full.x[:cfg.n_train], full.y[:cfg.n_train])
+            self.clients = partition(train, cfg.n_uavs, cfg.distribution,
+                                     cfg.seed)
         self.fleet = UAVFleet(cfg.n_uavs, cfg.channel, seed=cfg.seed + 1)
         self.devices = _heterogeneous_devices(cfg.n_uavs, self.rng,
                                               cfg.flops_range)
         self.workloads = [
             lat.WorkloadProfile(local_epochs=cfg.local_epochs,
                                 samples=len(c)) for c in self.clients]
-        self.params = cnn_mod.init_cnn(jax.random.PRNGKey(cfg.seed))
+        # a token model's handle; its buffers (the routers' correction
+        # bias) are drawn from the environment seed
+        self.model = (client_model(cfg.model_config(), cfg.seed)
+                      if cfg.token_model else None)
+        key = jax.random.PRNGKey(cfg.seed)
+        # the key is all the init reads
+        init = jax.jit(self.model.init) if self.model else cnn_mod.init_cnn  # analysis: ok=jit-donate
+        self.params = init(key)
+        self.expert_load = None
         self._test_x = jnp.asarray(self.test.x)
         self._test_y = jnp.asarray(self.test.y)
         # Pallas kernels run in interpret mode off-TPU
@@ -221,9 +321,13 @@ class HSFLSimulation:
         self._probe_epochs = self._static_schedule()
         self._stack_shard = self._batch_shard = None
         self._shard_ndev = 1
+        # clients stream through the round where K stacked copies of the
+        # model would not fit the device
+        self._streamed = cfg.use_fused_round and streams_users(
+            self.params, cfg.k_select)
         devs = jax.devices()
-        if cfg.use_fused_round and len(devs) > 1 and \
-                cfg.k_select % len(devs) == 0:
+        if cfg.use_fused_round and len(devs) > 1 and not self._streamed \
+                and cfg.k_select % len(devs) == 0:
             from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
             mesh = Mesh(np.array(devs), ("users",))
             self._stack_shard = NamedSharding(mesh, P("users"))
@@ -267,19 +371,42 @@ class HSFLSimulation:
         self._epoch_all = jax.jit(jax.vmap(epoch_fn))  # analysis: ok=jit-donate
         self._eval = jax.jit(eval_fn)  # analysis: ok=jit-donate
         from repro.kernels.fused_cnn.ops import ForwardPolicy
+        if cfg.token_model:
+            self._build_token_jits()
+            return
         self._fused = build_fused_round(
-            scheme=self.scheme, local_epochs=cfg.local_epochs,
-            steps_per_epoch=cfg.steps_per_epoch, lr=lr, tau_max=cfg.tau_max,
-            probe_epochs=self._probe_epochs,
-            async_weight=cfg.async_alpha * 2.0 ** (-cfg.async_a),
-            use_codec=cfg.use_delta_codec, interpret=self._interpret,
-            k_carry=cfg.k_select, codec_block=cfg.codec_block,
-            codec_bits=cfg.codec_bits,
+            **self._round_kwargs(),
             forward=ForwardPolicy(kernel=cfg.kernel,
                                   precision=cfg.precision,
                                   block_k=cfg.block_k,
                                   batch_users=cfg.batch_users).validate(),
-            stacked_sharding=self._stack_shard)
+            stacked_sharding=self._stack_shard, streamed=self._streamed)
+
+    def _round_kwargs(self):
+        cfg = self.cfg
+        return dict(scheme=self.scheme, local_epochs=cfg.local_epochs,
+                    steps_per_epoch=cfg.steps_per_epoch, lr=cfg.lr,
+                    tau_max=cfg.tau_max, probe_epochs=self._probe_epochs,
+                    async_weight=cfg.async_alpha * 2.0 ** (-cfg.async_a),
+                    use_codec=cfg.use_delta_codec,
+                    interpret=self._interpret, k_carry=cfg.k_select,
+                    codec_block=cfg.codec_block, codec_bits=cfg.codec_bits)
+
+    def _build_token_jits(self):
+        """Round and eval of a token model: its own training step (bf16
+        products against f32 master weights, f32 SGD) and held-out loss;
+        stacked, the step is autodiff of its logits."""
+        model = self.model
+        # eval reads the weights the next round trains
+        self._eval = jax.jit(model.evaluate(self.cfg.batch_size))  # analysis: ok=jit-donate
+        if self._streamed:
+            self._fused = build_fused_round(
+                **self._round_kwargs(), streamed=True,
+                client_step=model.loss_and_grad, load0=model.zero_load)
+        else:
+            self._fused = build_fused_round(
+                **self._round_kwargs(), forward=model.logits,
+                stacked_sharding=self._stack_shard)
 
     def evaluate(self) -> Tuple[float, float]:
         l, a = self._eval(self.params, self._test_x, self._test_y)
@@ -318,8 +445,9 @@ class HSFLSimulation:
         n_s = len(sched)
         sel = np.array([u.index for u in sched])
         xshape = self.clients[0].x.shape[1:]
-        xs = np.zeros((e, K, steps, bs) + xshape, np.float32)
-        ys = np.zeros((e, K, steps, bs), self.clients[0].y.dtype)
+        xs = np.zeros((e, K, steps, bs) + xshape, self.clients[0].x.dtype)
+        ys = np.zeros((e, K, steps, bs) + self.clients[0].y.shape[1:],
+                      self.clients[0].y.dtype)
         rates = np.zeros((e, K), np.float32)
         outs = np.zeros((e, K), bool)
         for e_i in range(e):
@@ -373,7 +501,9 @@ class HSFLSimulation:
         profiler spans ``hsfl.schedule``, ``hsfl.presample``, ``hsfl.launch``
         and ``hsfl.readback`` (inert unless a profiler trace is running), so
         a trace can lay the device's idle gaps over them; a round with
-        nobody selected stops after scheduling."""
+        nobody selected stops after scheduling.  A token model's launch
+        also counts its SGD ``steps`` and ``tokens``, and the streamed
+        round's expert load follows the readback as ``hsfl.experts``."""
         cfg = self.cfg
         with TraceAnnotation("hsfl.schedule"):
             sched, ue_bytes = self._schedule_round()
@@ -391,7 +521,9 @@ class HSFLSimulation:
                     [], delayed, self.params, cfg.async_alpha, cfg.async_a)
             return log, None
 
-        K = _k_bucket(len(sched), cfg.k_select)
+        # the streamed round serves every user count with one program
+        K = (cfg.k_select if self._streamed
+             else _k_bucket(len(sched), cfg.k_select))
         if self._shard_ndev > 1:
             # sharded user axis must stay divisible by the device count
             # (k_select is — the __init__ guard — so this stays ≤ k_select)
@@ -416,12 +548,21 @@ class HSFLSimulation:
             staged = xs.nbytes + ys.nbytes + sum(a.nbytes
                                                  for a in chan.values())
 
-        with TraceAnnotation("hsfl.launch", users=len(sched), K=K,
-                             staged_bytes=staged):
+        launch = dict(users=len(sched), K=K, staged_bytes=staged)
+        if cfg.token_model:
+            steps = len(sched) * cfg.local_epochs * cfg.steps_per_epoch
+            launch.update(steps=steps,
+                          tokens=steps * cfg.batch_size * cfg.seq_len)
+        load = None
+        with TraceAnnotation("hsfl.launch", **launch):
             xs = jax.device_put(xs, self._batch_shard)
             ys = jax.device_put(ys, self._batch_shard)
             chan = jax.device_put(chan)
-            if self.scheme.carries_delayed:
+            if self._streamed:
+                self.params, stats, load = self._fused(self.params, xs, ys,
+                                                       chan)
+                new_carry = None
+            elif self.scheme.carries_delayed:
                 stack, mask = (carry_delayed if carry_delayed is not None
                                else self._empty_carry())
                 self.params, c_stack, c_mask, stats = self._fused(
@@ -449,7 +590,26 @@ class HSFLSimulation:
                     # one-off activation payload m_a rides the SL uplink (eq. 12)
                     wl = self.workloads[u.index]
                     log.bytes_sent += wl.act_bytes_per_sample * wl.samples
+            if load:
+                load = jax.device_get(load)
+        if load:
+            self._expert_span(load)
         return log, new_carry
+
+    def _expert_span(self, load) -> None:
+        """The round's routed-expert load, written as the stats of the
+        ``hsfl.experts`` span: the most and the mean (token, slot) pairs a
+        held expert of a layer took over the round, and all pairs routed to
+        held experts.  ``expert_load`` keeps them and ``held``, the pairs
+        per user slot, local epoch, MoE layer and held expert."""
+        held = np.asarray(load["held"], np.int64)
+        per_expert = held.sum(axis=(0, 1))
+        stats = {"held_max": int(per_expert.max()),
+                 "held_mean": float(per_expert.mean()),
+                 "routed": int(held.sum())}
+        self.expert_load = dict(stats, held=held)
+        with TraceAnnotation("hsfl.experts", **stats):
+            pass
 
     # -- host reference engine ----------------------------------------------
     def _run_round_host(self, t: int, carry_delayed) -> Tuple[RoundLog, List[tuple]]:

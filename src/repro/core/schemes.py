@@ -246,6 +246,9 @@ class Scheme:
     uses_probes = False        # compile the Alg. 2 probe/snapshot block
     carries_delayed = False    # the async straggler carry is live
     supports_codec = False     # snapshots exist -> codec state is meaningful
+    # the aggregate is a weighted mean of per-user contributions, which
+    # ``pod_contribution`` gives one user at a time (the streamed round)
+    per_user_fold = True
 
     def with_pins(self, **pins) -> "Scheme":
         """A copy with sweep pins (b/τ_max/group statics) attached."""
@@ -467,6 +470,7 @@ class AsyncScheme(Scheme):
     """Async-HSFL: delayed updates arrive next round and aggregate with the
     polynomial staleness weight α(s+1)^(−a) [3]."""
     carries_delayed = True
+    per_user_fold = False
 
     def aggregate(self, params, contribs, snapshots, has_snap, arrived, *,
                   delayed=None, delayed_mask=None, async_weight: float = 0.0,
@@ -539,6 +543,7 @@ class OptTrimmedScheme(OptScheme):
     averaging, so a minority of exploded uploads cannot move the model.
     ``aggregate_host`` stacks the arrived list and calls the *same*
     ``trimmed_mean`` the device engines trace (host-vs-fused pinned)."""
+    per_user_fold = False
     trim = 0.25
 
     def aggregate(self, params, contribs, snapshots, has_snap, arrived, *,
@@ -562,6 +567,7 @@ class OptMedianScheme(OptScheme):
     """OPT with a coordinate-wise median aggregate — the max-breakdown
     member of the robust family (tolerates just under half the uploads
     being arbitrary)."""
+    per_user_fold = False
 
     def aggregate(self, params, contribs, snapshots, has_snap, arrived, *,
                   delayed=None, delayed_mask=None, async_weight: float = 0.0,
@@ -584,6 +590,7 @@ class OptClipScheme(OptScheme):
     """OPT with adaptive norm clipping: every update's delta is clipped
     to the median valid delta norm before the masked mean — cheap, and
     keeps honest-majority rounds near the plain mean."""
+    per_user_fold = False
 
     def aggregate(self, params, contribs, snapshots, has_snap, arrived, *,
                   delayed=None, delayed_mask=None, async_weight: float = 0.0,

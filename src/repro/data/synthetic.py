@@ -53,17 +53,58 @@ def make_digits(n: int, seed: int = 0, side: int = 28,
 
 
 def make_token_stream(n_seqs: int, seq_len: int, vocab: int,
-                      seed: int = 0) -> Dataset:
+                      seed=0) -> Dataset:
     """Zipf unigram + noisy-successor bigram LM data."""
     rng = np.random.default_rng(seed)
     ranks = np.arange(1, vocab + 1)
     probs = 1.0 / ranks
     probs /= probs.sum()
+    # rng.choice(vocab, n, p=probs), with the cdf built once
+    cdf = probs.cumsum()
+    cdf /= cdf[-1]
+
+    def zipf(n):
+        return cdf.searchsorted(rng.random(n), side="right")
+
     succ = rng.permutation(vocab)                # deterministic bigram skeleton
     toks = np.empty((n_seqs, seq_len + 1), np.int64)
-    toks[:, 0] = rng.choice(vocab, n_seqs, p=probs)
+    toks[:, 0] = zipf(n_seqs)
     for t in range(seq_len):
         follow = rng.random(n_seqs) < 0.7
-        toks[:, t + 1] = np.where(follow, succ[toks[:, t]],
-                                  rng.choice(vocab, n_seqs, p=probs))
+        toks[:, t + 1] = np.where(follow, succ[toks[:, t]], zipf(n_seqs))
     return Dataset(toks[:, :-1].astype(np.int32), toks[:, 1:].astype(np.int32))
+
+
+class _PoolRows:
+    """``n`` samples backed by a smaller pool: sample i is row i % len(rows)."""
+
+    def __init__(self, rows: np.ndarray, n: int) -> None:
+        self.rows, self.shape, self.dtype = rows, (n,) + rows.shape[1:], \
+            rows.dtype
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def __getitem__(self, idx):
+        return self.rows[np.asarray(idx) % len(self.rows)]
+
+
+def make_token_shards(sizes, pool: int, seq_len: int, vocab: int, seed,
+                      eval_seqs: int):
+    """Non-iid LM shards, one bigram skeleton per client.
+
+    Client u holds ``sizes[u]`` sequences of ``seq_len`` tokens, which
+    index a pool of ``pool`` sequences of its own stream
+    (``make_token_stream`` seeded by ``[seed, u]``), so that the host never
+    holds the whole corpus.  Each stream draws one row more; the held-out
+    set is that row of clients ``j * n // eval_seqs``, j < ``eval_seqs``.
+    Returns (the clients' ``Dataset``s, held-out ``Dataset``)."""
+    n = len(sizes)
+    streams = [make_token_stream(pool + 1, seq_len, vocab, seed=[seed, u])
+               for u in range(n)]
+    clients = [Dataset(_PoolRows(s.x[:pool], k), _PoolRows(s.y[:pool], k))
+               for s, k in zip(streams, sizes)]
+    held = [streams[j * n // eval_seqs] for j in range(eval_seqs)]
+    test = Dataset(np.stack([s.x[pool] for s in held]),
+                   np.stack([s.y[pool] for s in held]))
+    return clients, test

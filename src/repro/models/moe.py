@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models import layers
 from repro.models import module as m
 
 CAPACITY_FACTOR = 1.25
@@ -135,3 +136,134 @@ def moe_ffn(params, cfg: ModelConfig, x: jnp.ndarray,
     if dispatch == "dense":
         return moe_dense(params, cfg, x)
     return moe_scatter(params, cfg, x, act=act)
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3 routed experts (moonlight), this chip's share, no drops
+# ---------------------------------------------------------------------------
+
+def init_routed_moe(key, cfg: ModelConfig):
+    """Router over all ``num_experts``, the ``experts_held`` experts this
+    chip holds (stacked on a leading axis) and the shared experts, one
+    SwiGLU of width ``n_shared_experts * moe_d_ff``."""
+    pdt = m.dtype_of(cfg.param_dtype)
+    kr, ke, ks = jax.random.split(key, 3)
+    d, ff = cfg.d_model, cfg.moe_d_ff
+
+    def one_expert(k):
+        k1, k2, k3 = jax.random.split(k, 3)
+        return {"w_gate": m.dense_init(k1, d, ff, pdt),
+                "w_up": m.dense_init(k2, d, ff, pdt),
+                "w_down": m.dense_init(k3, ff, d, pdt)}
+
+    return {
+        "router": m.dense_init(kr, d, cfg.num_experts, pdt),
+        "experts": m.stack_layers(one_expert, ke, cfg.experts_held),
+        "shared": layers.init_mlp(ks, cfg, cfg.n_shared_experts * ff),
+    }
+
+
+def router_scores(logits: jnp.ndarray) -> jnp.ndarray:
+    """Expert affinities from router logits: DeepSeek-V3's sigmoid."""
+    return jax.nn.sigmoid(logits)
+
+
+def route_topk(router_w, bias, cfg: ModelConfig, x2d: jnp.ndarray):
+    """(weights (T, k), experts (T, k)) over all ``num_experts``.
+
+    Experts are chosen by the top-k of score + ``bias`` (the correction
+    bias, a buffer that takes no gradient); the chosen scores alone,
+    normalised to sum 1 and scaled by ``routed_scaling_factor``, weight
+    them.  The router's product runs at float32 ``highest``, so that a
+    low compute precision elsewhere does not reorder the top-k."""
+    logits = jnp.dot(x2d.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = router_scores(logits)
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias),
+                           cfg.experts_per_token)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scaling_factor, idx
+
+
+@jax.custom_vjp
+def _live_experts(experts, counts):
+    """``experts``, whose gradient is zeroed for the experts that took no
+    row: the grouped product's weight gradient leaves an empty group's
+    output unwritten on a TPU."""
+    return experts
+
+
+def _live_experts_fwd(experts, counts):
+    return experts, counts
+
+
+def _live_experts_bwd(counts, g):
+    return (jax.tree_util.tree_map(
+        lambda a: jnp.where((counts > 0).reshape((-1,) + (1,) * (a.ndim - 1)),
+                            a, 0), g), None)
+
+
+_live_experts.defvjp(_live_experts_fwd, _live_experts_bwd)
+
+
+def held_experts(experts, cfg: ModelConfig, x2d: jnp.ndarray, weights,
+                 idx):
+    """This chip's routed experts' part of the layer, with no token dropped.
+
+    ``idx`` (T, k) names experts over all ``num_experts``; the chip holds
+    ``[expert_offset, expert_offset + experts_held)``.  The (token, slot)
+    pairs are sorted by held expert (pairs routed elsewhere last) and each
+    expert's rows go through one grouped product (``lax.ragged_dot``);
+    pairs routed to absent experts add nothing here.  Returns ``(y (T, d)
+    float32, held (experts_held,) int32)``, ``held`` the rows each expert's
+    grouped product took."""
+    cd = m.dtype_of(cfg.dtype)
+    T, k = idx.shape
+    Eh = cfg.experts_held
+    local = idx.reshape(T * k) - cfg.expert_offset
+    held = (local >= 0) & (local < Eh)
+    key = jnp.where(held, local, Eh)
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.zeros((Eh + 1,), jnp.int32).at[key].add(1)[:Eh]
+    w = jnp.where(held, weights.reshape(T * k), 0.0)
+    # the grouped products leave the rows after the last group (pairs
+    # routed elsewhere) unwritten on a TPU: zero them on the way in and
+    # out, so that neither they nor their gradients reach a token
+    live = (jnp.arange(T * k) < jnp.sum(counts))[:, None]
+    experts = _live_experts(experts, counts)
+
+    def grouped(a, w):
+        return jnp.where(live, jax.lax.ragged_dot(
+            a, w.astype(cd), counts, preferred_element_type=jnp.float32),
+            0.0)
+
+    xs = jnp.where(live, x2d[order // k], 0.0).astype(cd)
+    h = jax.nn.silu(grouped(xs, experts["w_gate"])) \
+        * grouped(xs, experts["w_up"])
+    rows = grouped(h.astype(cd), experts["w_down"])
+    inv = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))
+    y = (rows[inv] * w[:, None]).reshape(T, k, -1).sum(axis=1)
+    return y, counts
+
+
+def _swiglu(p, x, cd):
+    gate = jnp.dot(x.astype(cd), p["w_gate"].astype(cd),
+                   preferred_element_type=jnp.float32)
+    up = jnp.dot(x.astype(cd), p["w_up"].astype(cd),
+                 preferred_element_type=jnp.float32)
+    return jnp.dot((jax.nn.silu(gate) * up).astype(cd),
+                   p["w_down"].astype(cd), preferred_element_type=jnp.float32)
+
+
+def routed_moe(params, bias, cfg: ModelConfig, x: jnp.ndarray):
+    """DeepSeek-V3 MoE FFN, this chip's share: the shared experts for every
+    token plus the held routed experts' weighted outputs.  x (B, S, d)
+    -> (y float32, held rows per expert)."""
+    B, S, d = x.shape
+    x2d = x.reshape(B * S, d)
+    w, idx = route_topk(params["router"], bias, cfg, x2d)
+    y, held = held_experts(params["experts"], cfg, x2d, w, idx)
+    y = y + _swiglu(params["shared"], x2d, m.dtype_of(cfg.dtype))
+    return y.reshape(B, S, d), held

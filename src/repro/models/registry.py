@@ -9,6 +9,7 @@ import jax
 from repro.configs.base import ModelConfig, get_config
 from repro.models import cnn as cnn_mod
 from repro.models import module as m
+from repro.models import moonlight
 from repro.models import transformer as tf
 
 
@@ -55,3 +56,39 @@ def get_model(arch_id: str) -> Model:
 
 def get_reduced_model(arch_id: str) -> Model:
     return build_model(get_config(arch_id).reduced())
+
+
+@dataclass(frozen=True)
+class ClientModel:
+    """What the HSFL round needs of a language model each client trains:
+    the trainable weights' ``init``, the non-trainable ``buffers`` (no
+    gradient, no FL delta, no codec), one client's training step
+    ``loss_and_grad(params, tokens, targets) -> (loss, grads, load)``
+    with ``zero_load`` the zero of its counters, ``logits(params,
+    tokens)`` and ``evaluate(batch)`` -> ``(params, tokens, targets) ->
+    (loss, accuracy)`` over held-out sequences ``batch`` at a time."""
+    cfg: ModelConfig
+    init: Callable[[jax.Array], Dict[str, Any]]
+    buffers: Any
+    loss_and_grad: Callable[..., Any]
+    logits: Callable[..., Any]
+    evaluate: Callable[[int], Callable[..., Any]]
+    zero_load: Any
+
+
+def client_model(cfg: ModelConfig, seed: int = 0) -> ClientModel:
+    """The round's handle on ``cfg``; ``seed`` draws its buffers.  The
+    round trains DeepSeek-V3-style models (latent attention, routed
+    experts) only."""
+    if not cfg.kv_lora_rank:
+        raise NotImplementedError(
+            f"{cfg.name}: the HSFL round trains models with latent "
+            f"attention and routed experts (DeepSeek-V3 blocks) only")
+    bias = moonlight.correction_bias(seed, cfg)
+    return ClientModel(
+        cfg=cfg, init=lambda key: moonlight.init(key, cfg), buffers=bias,
+        loss_and_grad=moonlight.make_loss_grad(cfg, bias),
+        logits=lambda p, tokens: moonlight.logits(
+            p, cfg, moonlight.hidden(p, bias, cfg, tokens)[0]),
+        evaluate=lambda batch: moonlight.make_evaluate(cfg, bias, batch),
+        zero_load=moonlight.zero_load(cfg))
