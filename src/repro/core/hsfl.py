@@ -30,7 +30,7 @@ Two round engines share the control plane:
 from __future__ import annotations
 
 from dataclasses import dataclass, field, fields
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -273,6 +273,16 @@ def _k_bucket(n_sched: int, k_select: int) -> int:
     return min(k_select, 2 * ((n_sched + 1) // 2))
 
 
+class _RoundPlan(NamedTuple):
+    """A fused round's host work, done before its launch: the schedule
+    and, where anyone is selected, the inputs staged on the device, the
+    payloads its byte accounting reads and the ``hsfl.launch`` stats."""
+    sched: list
+    payload: Optional[np.ndarray] = None
+    inputs: tuple = ()                 # xs, ys, chan, on the device
+    launch: Optional[dict] = None
+
+
 class HSFLSimulation:
     """Control plane composing jitted local training (fused or host loop)."""
 
@@ -319,6 +329,9 @@ class HSFLSimulation:
         # over float32 bytes for this model, not a hand-set scalar
         self.compress_ratio = model_compress_ratio(cfg)
         self._probe_epochs = self._static_schedule()
+        # the next fused round's plan, prepared while this round's program
+        # runs (``_run_round_fused``)
+        self._next_plan: Optional[_RoundPlan] = None
         self._stack_shard = self._batch_shard = None
         self._shard_ndev = 1
         # clients stream through the round where K stacked copies of the
@@ -496,31 +509,15 @@ class HSFLSimulation:
             self.params)
         return (stack, jax.device_put(np.zeros((k,), bool)))
 
-    def _run_round_fused(self, t: int, carry_delayed):
-        """One round on the fused engine.  Its host stages run under the
-        profiler spans ``hsfl.schedule``, ``hsfl.presample``, ``hsfl.launch``
-        and ``hsfl.readback`` (inert unless a profiler trace is running), so
-        a trace can lay the device's idle gaps over them; a round with
-        nobody selected stops after scheduling.  A token model's launch
-        also counts its SGD ``steps`` and ``tokens``, and the streamed
-        round's expert load follows the readback as ``hsfl.experts``."""
+    def _prepare_round(self) -> _RoundPlan:
+        """Schedule a round and, where anyone is selected, presample it and
+        stage its inputs on the device, under the spans ``hsfl.schedule``
+        and ``hsfl.presample``."""
         cfg = self.cfg
         with TraceAnnotation("hsfl.schedule"):
             sched, ue_bytes = self._schedule_round()
-        log = RoundLog(round=t, selected=len(sched))
-        if isinstance(carry_delayed, (list, tuple)) and not carry_delayed:
-            carry_delayed = None
-
         if not sched:
-            # nothing selected: stragglers (async) still merge on the server
-            if self.scheme.carries_delayed and carry_delayed is not None:
-                stack, mask = carry_delayed
-                delayed = [(jax.tree_util.tree_map(lambda a: a[i], stack), 1)
-                           for i in range(mask.shape[0]) if bool(mask[i])]
-                self.params = self.scheme.aggregate_host(
-                    [], delayed, self.params, cfg.async_alpha, cfg.async_a)
-            return log, None
-
+            return _RoundPlan(sched)
         # the streamed round serves every user count with one program
         K = (cfg.k_select if self._streamed
              else _k_bucket(len(sched), cfg.k_select))
@@ -547,17 +544,59 @@ class HSFLSimulation:
             }
             staged = xs.nbytes + ys.nbytes + sum(a.nbytes
                                                  for a in chan.values())
-
+            inputs = (jax.device_put(xs, self._batch_shard),
+                      jax.device_put(ys, self._batch_shard),
+                      jax.device_put(chan))
         launch = dict(users=len(sched), K=K, staged_bytes=staged)
         if cfg.token_model:
             steps = len(sched) * cfg.local_epochs * cfg.steps_per_epoch
             launch.update(steps=steps,
                           tokens=steps * cfg.batch_size * cfg.seq_len)
+        return _RoundPlan(sched, payload, inputs, launch)
+
+    def _run_round_fused(self, t: int, carry_delayed):
+        """One round on the fused engine.  Its host stages run under the
+        profiler spans ``hsfl.schedule``, ``hsfl.presample``, ``hsfl.launch``
+        and ``hsfl.readback`` (inert unless a profiler trace is running), so
+        a trace can lay the device's idle gaps over them; a round with
+        nobody selected stops after scheduling.  A token model's launch
+        also counts its SGD ``steps`` and ``tokens``, and the streamed
+        round's expert load follows the readback as ``hsfl.experts``.
+
+        Once this round's program is dispatched, the next round is
+        scheduled, presampled and staged while the device runs it (its
+        ``hsfl.schedule`` and ``hsfl.presample`` nest in this round's
+        ``hsfl.round``, before ``hsfl.readback``); the next call launches
+        that plan, and its ``hsfl.launch`` says so with the stat ``ahead``
+        = 1.  Neither stage reads a round's results, and nothing else
+        draws from the fleet or ``self.rng`` between them, so every round's
+        inputs are those of the serial order.  A round that dispatched
+        nothing prepares nothing ahead, and the first round prepares itself.
+        The last round of ``run`` thus prepares one round that never runs:
+        one presample more per simulation, which changes no result."""
+        cfg = self.cfg
+        plan, self._next_plan = self._next_plan, None
+        ahead = plan is not None
+        if not ahead:
+            plan = self._prepare_round()
+        sched = plan.sched
+        log = RoundLog(round=t, selected=len(sched))
+        if isinstance(carry_delayed, (list, tuple)) and not carry_delayed:
+            carry_delayed = None
+
+        if not sched:
+            # nothing selected: stragglers (async) still merge on the server
+            if self.scheme.carries_delayed and carry_delayed is not None:
+                stack, mask = carry_delayed
+                delayed = [(jax.tree_util.tree_map(lambda a: a[i], stack), 1)
+                           for i in range(mask.shape[0]) if bool(mask[i])]
+                self.params = self.scheme.aggregate_host(
+                    [], delayed, self.params, cfg.async_alpha, cfg.async_a)
+            return log, None
+
         load = None
-        with TraceAnnotation("hsfl.launch", **launch):
-            xs = jax.device_put(xs, self._batch_shard)
-            ys = jax.device_put(ys, self._batch_shard)
-            chan = jax.device_put(chan)
+        with TraceAnnotation("hsfl.launch", **plan.launch, ahead=int(ahead)):
+            xs, ys, chan = plan.inputs
             if self._streamed:
                 self.params, stats, load = self._fused(self.params, xs, ys,
                                                        chan)
@@ -572,6 +611,9 @@ class HSFLSimulation:
                 self.params, stats = self._fused(self.params, xs, ys, chan)
                 new_carry = None
 
+        # the next round's host work, while the device runs this round
+        self._next_plan = self._prepare_round()
+
         # the host waits here for the round program
         with TraceAnnotation("hsfl.readback"):
             arrived = np.asarray(stats.arrived)
@@ -584,7 +626,7 @@ class HSFLSimulation:
             log.delayed = int(delayed.sum())
             log.dropped = int(dropped.sum())
             events = sends + arrived.astype(np.int64)
-            log.bytes_sent = float(np.sum(payload * events))
+            log.bytes_sent = float(np.sum(plan.payload * events))
             for j, u in enumerate(sched):
                 if u.mode == "SL" and events[j] > 0:
                     # one-off activation payload m_a rides the SL uplink (eq. 12)

@@ -13,6 +13,12 @@ TINY = dict(num_layers=3, d_model=64, num_heads=4, head_dim=16,
             experts_held=4, expert_offset=2, vocab_size=256,
             dtype="float32")
 
+# the same widths as the config.json keys an ``HSFLConfig`` names
+TINY_KEYS = dict(hidden_size=64, num_attention_heads=4, qk_nope_head_dim=16,
+                 qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=32,
+                 intermediate_size=128, moe_intermediate_size=32,
+                 n_routed_experts=8, num_experts_per_tok=3, vocab_size=256)
+
 
 def tiny(**kw):
     return get_config("moonlight-16b-a3b").replace(**{**TINY, **kw})

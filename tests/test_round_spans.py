@@ -2,11 +2,13 @@
 
 ``HSFLSimulation._run_round_fused`` opens the host spans ``hsfl.round``,
 ``hsfl.schedule``, ``hsfl.presample``, ``hsfl.launch`` and
-``hsfl.readback``; ``build_fused_round`` names its device work
-``local_epoch``, ``probe`` and ``aggregate``.  The chip benchmark's
-per-layer readers match the span names and stats in a profiler trace, so
-the tests pin them as a CPU trace records them; the scopes are pinned in
-the compiled program's ``op_name`` metadata, where they reach.
+``hsfl.readback``; the next round's schedule and presample run inside a
+round, between its launch and its readback.  ``build_fused_round`` names
+its device work ``local_epoch``, ``probe`` and ``aggregate``.  The chip
+benchmark's per-layer readers match the span names and stats in a
+profiler trace, so the tests pin them as a CPU trace records them; the
+scopes are pinned in the compiled program's ``op_name`` metadata, where
+they reach.
 """
 import glob
 import re
@@ -22,7 +24,11 @@ from repro.core.hsfl import HSFLConfig, HSFLSimulation, _k_bucket
 from repro.kernels.fused_cnn.ops import ForwardPolicy
 from repro.models.cnn import init_cnn
 
-STAGES = ["hsfl.schedule", "hsfl.presample", "hsfl.launch", "hsfl.readback"]
+# round 1 prepares itself, later rounds launch what the one before staged;
+# each prepares the next round before it reads its own stats back
+FIRST = ["hsfl.schedule", "hsfl.presample", "hsfl.launch", "hsfl.schedule",
+         "hsfl.presample", "hsfl.readback"]
+LATER = ["hsfl.launch", "hsfl.schedule", "hsfl.presample", "hsfl.readback"]
 
 
 def small_cfg(**kw):
@@ -35,25 +41,32 @@ def small_cfg(**kw):
 
 def traced_rounds(cfg, tmp_path, rounds=3, monkeypatch=None):
     """Run ``rounds`` fused rounds under a profiler trace; return the
-    logs, the bytes each round handed to ``jax.device_put`` and the
-    ``hsfl.*`` host events as (name, start, end, stats)."""
+    logs, the bytes handed to ``jax.device_put`` for each round's inputs
+    (those put after a round's schedule and before the next one's) and
+    the ``hsfl.*`` host events as (name, start, end, stats)."""
     sim = HSFLSimulation(cfg)
     staged = []
-    put = jax.device_put
+    put, schedule = jax.device_put, sim._schedule_round
 
     def counting_put(x, *a, **k):
         staged[-1] += sum(np.asarray(leaf).nbytes
                           for leaf in jax.tree_util.tree_leaves(x))
         return put(x, *a, **k)
 
+    def counting_schedule():
+        staged.append(0)
+        return schedule()
+
     monkeypatch.setattr(jax, "device_put", counting_put)
+    monkeypatch.setattr(sim, "_schedule_round", counting_schedule)
     logs, carry = [], None
     with jax.profiler.trace(str(tmp_path)):
         for t in range(1, rounds + 1):
-            staged.append(0)
             log, carry = sim.run_round(t, carry)
             logs.append(log)
     monkeypatch.undo()
+    # the last round prepared one more, which never runs
+    staged = staged[:rounds]
     pd = ProfileData.from_file(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
                                          recursive=True)[0])
     events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
@@ -81,15 +94,18 @@ def test_round_spans_nest_in_order_with_their_stats(codec, tmp_path,
     for rnd, log, nbytes in zip(rounds, logs, staged):
         assert rnd[3] == {"round": log.round}
         inner = children(events, rnd)
-        assert [ev[0] for ev in inner] == STAGES
+        assert [ev[0] for ev in inner] == (FIRST if log.round == 1
+                                           else LATER)
         for a, b in zip(inner, inner[1:]):
             assert a[2] <= b[1], f"{a[0]} overlaps {b[0]}"
-        launch = inner[2][3]
+        (launch,) = [ev[3] for ev in inner if ev[0] == "hsfl.launch"]
         assert launch["users"] == log.selected > 0
         assert launch["K"] == _k_bucket(log.selected, cfg.k_select)
         assert launch["staged_bytes"] == nbytes > 0
-        for ev in inner[:2] + inner[3:]:
-            assert ev[3] == {}
+        assert launch["ahead"] == (log.round > 1)
+        for ev in inner:
+            if ev[0] != "hsfl.launch":
+                assert ev[3] == {}
     if not codec:
         assert any(ev[3]["K"] > ev[3]["users"] for ev in events
                    if ev[0] == "hsfl.launch"), "fixture no longer pads K"

@@ -12,7 +12,7 @@ import pytest
 from jax.profiler import ProfileData
 
 from moonlight_tiny import params as tiny_params
-from moonlight_tiny import ref_config, register_tiny, tiny
+from moonlight_tiny import TINY_KEYS, ref_config, register_tiny, tiny
 from repro.core import fused_round
 from repro.core.fused_round import build_fused_round, streams_users
 from repro.core.hsfl import (HSFLConfig, HSFLSimulation, init_params,
@@ -162,12 +162,6 @@ def test_codec_ratio_comes_from_the_configured_tree():
     lm = HSFLConfig(use_delta_codec=True, model="moonlight-16b-a3b",
                     layers=5, experts_held=8, vocab_size=20480)
     assert 0.25 < model_compress_ratio(lm) < model_compress_ratio(cnn)
-
-
-TINY_KEYS = dict(hidden_size=64, num_attention_heads=4, qk_nope_head_dim=16,
-                 qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=32,
-                 intermediate_size=128, moe_intermediate_size=32,
-                 n_routed_experts=8, num_experts_per_tok=3, vocab_size=256)
 
 
 def test_hsfl_round_on_the_tiny_model_matches_the_reference(monkeypatch,
