@@ -97,11 +97,7 @@ def _repo_relative(filename: str) -> str:
 
 
 def _site(eqn: jcore.JaxprEqn) -> BufferSite:
-    frame = None
-    try:
-        frame = _siu.user_frame(eqn.source_info)
-    except Exception:
-        pass
+    frame = _siu.user_frame(eqn.source_info.traceback)
     if frame is None:
         return BufferSite("<jax-internal>", 0, eqn.primitive.name)
     return BufferSite(_repo_relative(frame.file_name), frame.start_line,

@@ -1,64 +1,38 @@
-"""Fused HSFL communication round — Algorithms 1 & 2 as one device program.
+"""The HSFL round (Algorithms 1 & 2) as device programs: one core, three
+drivers.
 
-The host-loop reference (``HSFLSimulation._run_round_host``) pays hundreds of
-dispatch round-trips per simulated round: per-epoch batch conversion, per-user
-``user_tree(i)`` slicing, per-user Python ``OppTransmitter`` probes and an
-O(K) aggregation loop.  This module compiles the whole round into a single
-jitted function:
+The core is Alg. 2's transmission logic, written once:
 
-  - the K selected users live on a leading stacked axis (one ``vmap``);
-  - the e local epochs run as ``lax.scan`` segments inside one jit, with the
-    per-user SGD step lowered through the ``kernels/fused_cnn`` forward
-    policy — by default the pool-first fused step with a hand-written VJP
-    and a closed-form softmax-CE cotangent (bit-identical forward to
-    ``cnn.forward_im2col`` at f32; ``kernel="pallas"`` routes the same
-    algorithm through the Pallas kernel suite, ``precision="bf16"`` runs
-    bf16 compute against f32 master params, ``kernel="im2col"`` restores
-    the PR-1 autodiff step);
-  - the OPT scheduler (eqs. 14–16: scheduled probes, outage voids, snapshot
-    overwrite, τ_extra bookkeeping) runs on-device and branch-free through
-    ``opportunistic_sync.snapshot_decision`` — the same algorithmic core the
-    multi-pod OppSync feature uses, so Alg. 2 has one implementation;
-  - the round ends with a single masked weighted-mean aggregation over the
-    K axis (no per-user tree_map loop);
-  - every scheme-specific decision (probe schedule, final deadline,
-    aggregation) dispatches through a registered ``schemes.Scheme`` object
-    — the engine bodies hold no per-scheme string branches, so registered
-    schemes (incl. beyond-paper ones like ``deadline``) compile here
-    unchanged;
-  - with ``use_codec`` the snapshot state is the int8 delta-codec payload
-    (kernels/delta_codec): probes quantize params−base through the Pallas
-    kernel and rescues dequantize at aggregation, so the rescued
-    contribution carries real quantization noise and the eq. 15 payload
-    uses the actual int8+scale byte count.
+- ``schemes.probe`` — a scheduled probe's eq. 15 delay and its eqs. 14–16
+  snapshot decision (outage voids, τ_extra budget);
+- ``snapshot_format`` — what a snapshot is: the stacked (K, ...) params, or
+  with the delta codec the int8 state of params − round-start global
+  (``kernels/delta_codec``), so a rescued contribution carries real
+  quantization noise;
+- ``final_arrival`` — whether the final upload arrives (Alg. 2 l. 14);
+- ``settle`` — the round's masks after the scheme's ``aggregate``.
 
-Two round builders share these pieces:
+Every scheme-specific decision (probe schedule, final slack, aggregate,
+straggler carry) dispatches through a registered ``schemes.Scheme``; no
+driver branches on a scheme's name.  The drivers:
 
-- ``build_fused_round`` — inputs presampled host-side once per round
-  (``hsfl._presample_round``): batch tensors of shape (e, K, steps, bs, ...)
-  and per-epoch rate/outage tensors, one host→device transfer per round
-  instead of e·K.  The probe *schedule* (Alg. 2 line 12 / the manual
-  override of Sec. III-B) is static per configuration, so probes are
-  compiled only at scheduled epoch boundaries; everything data-dependent
-  (outages, τ budget, arrival, rescue, staleness) stays branch-free
-  on-device.  This path replays the host numpy RNG streams bit-for-bit
-  (the fused-vs-host equivalence contract).  Its streamed form
-  (``streamed=True``, picked by ``streams_users`` where K stacked copies
-  of the model would not fit the device) takes the same inputs and trains
-  one user at a time, folding each into a running sum.
-- ``build_device_round`` — the whole control plane on-device: channel/
-  mobility from a ``channel_lib.FleetState`` carry, greedy selection via
-  ``selection.select_users_jax``, batches gathered in-program, epochs
-  scanned, eval in-program.  This is the round the sweep engine
-  (``core/sweep``) chains with ``lax.scan`` and vmaps over seeds/configs.
-  ``use_codec`` gives it the same int8 snapshot path: the codec state
-  (int8 blocks + scales) rides the epoch scan carry, and the derived
-  ``compress_ratio`` feeds selection, τ budgeting and byte metrics.
+- stacked (``build_fused_round``) — inputs presampled host-side once per
+  round (``hsfl._presample_round``), the K users on a leading axis, epochs
+  unrolled with probes compiled only at the static scheduled epochs.  It
+  replays the host numpy RNG streams bit for bit;
+- streamed (``build_fused_round(streamed=True)``, picked by
+  ``streams_users`` where K stacked copies of the model would not fit) —
+  the same inputs, one user trained at a time and folded into a running
+  sum;
+- device (``build_device_round``) — the whole control plane in-program
+  (fleet channel, selection, batch gathers, eval), epochs scanned with
+  masked probes; the sweep engine (``core/sweep``) chains it with
+  ``lax.scan`` and vmaps it over seeds and configs.
 """
 from __future__ import annotations
 
 import os
-from dataclasses import replace as _dc_replace
+from dataclasses import dataclass, replace as _dc_replace
 from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
@@ -67,10 +41,9 @@ import jax.numpy as jnp
 from repro.core.channel_lib import (ChannelParams, FleetState,
                                     fleet_move, fleet_outage_step,
                                     fleet_rates, fleet_resample_fading)
-from repro.core.opportunistic_sync import snapshot_decision
-from repro.core.schemes import (get_scheme, kx as _kx,
+from repro.core.schemes import (get_scheme,
                                 masked_mean as _masked_mean,  # noqa: F401
-                                probe_schedule_mask,
+                                probe, probe_schedule_mask,
                                 tree_where_k as _tree_where_k)
 from repro.kernels.delta_codec.kernel import (BLOCK, dequantize_blocks,
                                               quantize_blocks)
@@ -93,35 +66,93 @@ class RoundStats(NamedTuple):
     opp_sends: jnp.ndarray   # (K,) int32 — opportunistic transmissions sent
 
 
-def _codec_encode(stacked, params, interpret: bool, block: int = BLOCK,
-                  bits: int = 8):
-    """Quantize the stacked users' delta vs the round-start global params
-    into the int8 codec state ``(q (K, M, block), scales (K, M, 1))``."""
-    delta = jax.tree_util.tree_map(lambda s, p: s - p[None], stacked, params)
-    flat, _ = stacked_flatten(delta, block=block)
-    k, rows, blk = flat.shape
-    q, s = quantize_blocks(flat.reshape(k * rows, blk), interpret=interpret,
-                           bits=bits)
-    return q.reshape(k, rows, blk), s.reshape(k, rows, 1)
+# ---------------------------------------------------------------------------
+# the core: snapshot format, final arrival, settlement
+# ---------------------------------------------------------------------------
+
+class StackedSnapshots:
+    """A snapshot is the users' stacked (K, ...) params themselves."""
+
+    def empty(self, stacked):
+        return stacked
+
+    def take(self, ok, stacked, params, snap):
+        return _tree_where_k(ok, stacked, snap)
+
+    def restore(self, snap, stacked, params):
+        return snap
 
 
-def _codec_decode(q, s, stacked_like, params, interpret: bool):
-    """Dequantize the codec state back to a stacked params pytree — the
-    rescued contribution carries true int8 quantization noise."""
-    k, rows, blk = q.shape
-    flat = dequantize_blocks(q.reshape(k * rows, blk),
-                             s.reshape(k * rows, 1), interpret=interpret)
-    delta = stacked_unflatten(flat.reshape(k, rows, blk), stacked_like)
-    return jax.tree_util.tree_map(lambda d, p: p[None] + d, delta, params)
+@dataclass(frozen=True)
+class CodecSnapshots:
+    """A snapshot is the int8 delta-codec state ``(q (K, M, block), scales
+    (K, M, 1))`` of the users' params less the round-start global params;
+    the eq. 15 payload counts its int8+scale bytes (``compress_ratio``), and
+    the device round's epoch scan carries about 4x fewer snapshot bytes."""
+    interpret: bool
+    block: int = BLOCK
+    bits: int = 8
+
+    def empty(self, stacked):
+        """All-zero state (decodes to the global params; never aggregated
+        before a probe succeeds — ``has_snap`` gates)."""
+        flat, _ = stacked_flatten(stacked, block=self.block)
+        return (jnp.zeros(flat.shape, jnp.int8),
+                jnp.zeros(flat.shape[:2] + (1,), jnp.float32))
+
+    def take(self, ok, stacked, params, snap):
+        """Quantize the users' delta through the Pallas kernel; keep it
+        where ``ok``."""
+        delta = jax.tree_util.tree_map(lambda s, p: s - p[None], stacked,
+                                       params)
+        flat, _ = stacked_flatten(delta, block=self.block)
+        k, rows, blk = flat.shape
+        q, s = quantize_blocks(flat.reshape(k * rows, blk),
+                               interpret=self.interpret, bits=self.bits)
+        return _tree_where_k(ok, (q.reshape(k, rows, blk),
+                                  s.reshape(k, rows, 1)), snap)
+
+    def restore(self, snap, stacked, params):
+        """Dequantize back to a stacked params pytree."""
+        q, s = snap
+        k, rows, blk = q.shape
+        flat = dequantize_blocks(q.reshape(k * rows, blk),
+                                 s.reshape(k * rows, 1),
+                                 interpret=self.interpret)
+        delta = stacked_unflatten(flat.reshape(k, rows, blk), stacked)
+        return jax.tree_util.tree_map(lambda d, p: p[None] + d, delta, params)
 
 
-def _codec_zero_state(stacked, block: int = BLOCK):
-    """All-zero codec state shaped for ``stacked`` (decodes to the global
-    params; never aggregated before a probe succeeds — ``has_snap`` gates)."""
-    flat, _ = stacked_flatten(stacked, block=block)
-    return (jnp.zeros(flat.shape, jnp.int8),
-            jnp.zeros(flat.shape[:2] + (1,), jnp.float32))
+def snapshot_format(scheme, use_codec: bool, interpret: bool,
+                    block: int = BLOCK, bits: int = 8):
+    """The round's snapshot format: ``empty(stacked)``, ``take(ok,
+    stacked, params, snap)`` and ``restore(snap, stacked, params)``.  The
+    codec applies only to schemes whose snapshots exist."""
+    if use_codec and scheme.supports_codec:
+        return CodecSnapshots(interpret, block, bits)
+    return StackedSnapshots()
 
+
+def final_arrival(scheme, valid, final_outage, final_rate, payload_bits,
+                  train_time, tau_extra0, tau_max):
+    """Alg. 2 l. 14: the final upload arrives if the link is up and
+    training, the scheme's slack and the upload fit tau_max."""
+    tau_f = payload_bits / jnp.maximum(final_rate, 1e-9)
+    fits = train_time + scheme.final_slack(tau_extra0) + tau_f <= tau_max
+    return valid & (~final_outage) & fits
+
+
+def settle(scheme, valid, arrived, rescued, opp_sends) -> RoundStats:
+    """The round's outcome once the aggregate has named the rescued: who
+    enters the straggler carry, and who contributed nothing."""
+    delayed = scheme.delayed_out(valid, arrived)
+    dropped = valid & ~arrived & ~rescued & ~delayed
+    return RoundStats(arrived, rescued, delayed, dropped, opp_sends)
+
+
+# ---------------------------------------------------------------------------
+# local training, and whether K stacked users fit the device
+# ---------------------------------------------------------------------------
 
 def _resolve_epoch_fns(forward: Any, lr: float, interpret: bool
                        ) -> Tuple[Callable, Callable]:
@@ -197,6 +228,10 @@ def sgd(params, grads, lr: float):
     return jax.tree_util.tree_map(lambda w, g: w - lr * g, params, grads)
 
 
+# ---------------------------------------------------------------------------
+# the stacked and streamed drivers: presampled inputs
+# ---------------------------------------------------------------------------
+
 def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
                       lr: float, tau_max: float, probe_epochs: Tuple[int, ...],
                       async_weight: float = 0.0, use_codec: bool = False,
@@ -208,21 +243,20 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
                       load0: Any = None) -> Callable:
     """Compile one HSFL round for a fixed (scheme, e, steps, schedule).
 
-    Returns ``round_fn(params, xs, ys, chan)`` for opt/discard, or
-    ``round_fn(params, delayed_stack, delayed_mask, xs, ys, chan)`` for
-    async (``delayed_stack`` leaves are (k_carry, ...)).  ``chan`` is a dict
-    of device arrays: rates/outages (e, K), payload_bits/tau_extra0/
-    final_rate/train_time (K,), final_outage/valid (K,) bool.  The result is
-    ``(new_params, stats)`` plus ``new_delayed_stack`` for async.
+    Returns ``round_fn(params, xs, ys, chan)`` for schemes without a
+    straggler carry, or ``round_fn(params, delayed_stack, delayed_mask, xs,
+    ys, chan)`` for those with one (async; ``delayed_stack`` leaves are
+    (k_carry, ...)).  ``chan`` is a dict of device arrays: rates/outages
+    (e, K), payload_bits/tau_extra0/final_rate/train_time (K,),
+    final_outage/valid (K,) bool.  The result is ``(new_params, stats)``,
+    with the next ``delayed_stack, delayed_mask`` before ``stats`` for a
+    straggler carry.
 
-    ``scheme`` is a registered ``schemes.Scheme`` (or its name): its
-    ``final_slack`` shapes the arrival predicate and its ``aggregate``
-    merges the round — the engine body holds no per-scheme branches beyond
-    the static ``carries_delayed`` signature split.  ``forward`` is a
-    ``kernels/fused_cnn.ForwardPolicy`` (or ``None`` for
+    ``scheme`` is a registered ``schemes.Scheme`` (or its name).
+    ``forward`` is a ``kernels/fused_cnn.ForwardPolicy`` (or ``None`` for
     the default xla/f32 policy; a bare callable is a legacy hook used by
     tests that push non-CNN models through the round).  The round carries
-    are **donated**: the caller's ``params`` (and, for async, the straggler
+    are **donated**: the caller's ``params`` (and the straggler
     ``delayed_stack``/``delayed_mask``) buffers alias the returned ones, so
     chaining rounds the way ``HSFLSimulation`` does stops copying the full
     parameter state every dispatch — do not reuse those arrays after the
@@ -250,14 +284,17 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
             tau_max=tau_max, probe_epochs=probe_epochs, use_codec=use_codec,
             client_step=client_step, load0=load0)
     epoch_all, _ = _resolve_epoch_fns(forward, lr, interpret)
+    fmt = snapshot_format(scheme, use_codec, interpret, codec_block,
+                          codec_bits)
 
     if scheme.carries_delayed and k_carry < 1:
         raise ValueError(
             f"{scheme.name} build_fused_round needs k_carry >= 1 (the fixed "
             f"width of the straggler carry), got k_carry={k_carry}")
 
-    def _train_and_probe(params, xs, ys, chan):
-        k = chan["valid"].shape[0]
+    def body(params, xs, ys, chan, **carry):
+        valid = chan["valid"]
+        k = valid.shape[0]
         stacked = jax.tree_util.tree_map(
             lambda a: jnp.broadcast_to(a[None], (k,) + a.shape), params)
         if stacked_sharding is not None:
@@ -269,8 +306,7 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
         tau_extra = chan["tau_extra0"]
         has_snap = jnp.zeros((k,), bool)
         nsent = jnp.zeros((k,), jnp.int32)
-        snap = (_codec_zero_state(stacked, codec_block) if use_codec
-                else stacked)
+        snap = fmt.empty(stacked)
 
         # epochs advance in lockstep; the probe schedule is static, so the
         # OPT transmission logic is only compiled at scheduled boundaries.
@@ -283,49 +319,32 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
                 with jax.named_scope("probe"):
                     rate = chan["rates"][e_t - 1]
                     outage = chan["outages"][e_t - 1]
-                    # eq. 15
-                    tau = chan["payload_bits"] / jnp.maximum(rate, 1e-9)
-                    ok, tau_extra = snapshot_decision(chan["valid"], outage,
-                                                      tau, tau_extra)
-                    if use_codec:
-                        q_new, s_new = _codec_encode(stacked, params,
-                                                     interpret, codec_block,
-                                                     codec_bits)
-                        snap = (jnp.where(_kx(ok, q_new), q_new, snap[0]),
-                                jnp.where(_kx(ok, s_new), s_new, snap[1]))
-                    else:
-                        snap = _tree_where_k(ok, stacked, snap)
+                    ok, tau_extra = probe(valid, outage, rate,
+                                          chan["payload_bits"], tau_extra)
+                    snap = fmt.take(ok, stacked, params, snap)
                     has_snap = has_snap | ok
                     nsent = nsent + ok.astype(jnp.int32)
-        return stacked, snap, has_snap, nsent
-
-    def _round_sync(params, stacked, snap, has_snap, arrived):
-        """Scheme aggregation: masked mean over finals (+ rescues)."""
-        if scheme.uses_probes and use_codec:
-            snap = _codec_decode(snap[0], snap[1], stacked, params,
-                                 interpret)
-        return scheme.aggregate(params, stacked, snap, has_snap, arrived)
+        with jax.named_scope("aggregate"):
+            arrived = final_arrival(
+                scheme, valid, chan["final_outage"], chan["final_rate"],
+                chan["payload_bits"], chan["train_time"], chan["tau_extra0"],
+                tau_max)
+            new_params, rescued = scheme.aggregate(
+                params, stacked, fmt.restore(snap, stacked, params),
+                has_snap, arrived, **carry)
+            stats = settle(scheme, valid, arrived, rescued, nsent)
+        return new_params, stacked, stats
 
     if not scheme.carries_delayed:
 
         def round_fn(params, xs, ys, chan):
-            stacked, snap, has_snap, nsent = _train_and_probe(
-                params, xs, ys, chan)
-            with jax.named_scope("aggregate"):
-                arrived = _final_arrival(scheme, chan, tau_max)
-                new_params, rescued = _round_sync(params, stacked, snap,
-                                                  has_snap, arrived)
-                delayed = scheme.delayed_out(chan["valid"], arrived)
-                dropped = chan["valid"] & ~arrived & ~rescued & ~delayed
-            return new_params, RoundStats(arrived, rescued, delayed,
-                                          dropped, nsent)
+            new_params, _, stats = body(params, xs, ys, chan)
+            return new_params, stats
 
         # params -> new_params aliases in place: the round loop stops
         # copying the global model every dispatch
         return jax.jit(round_fn, donate_argnums=(0,))
 
-    # -- staleness-carrying schemes (async): the straggler stack/mask ride
-    #    the round signature and the scheme's aggregate merges them --------
     aw = float(async_weight)
 
     def round_fn(params, delayed_stack, delayed_mask, xs, ys, chan):
@@ -336,38 +355,22 @@ def build_fused_round(*, scheme: Any, local_epochs: int, steps_per_epoch: int,
                 f"straggler carry is only k_carry={k_carry} wide; "
                 f"build_fused_round needs k_carry >= the padded user bucket "
                 f"K (pass k_carry=k_select as HSFLSimulation does)")
-        stacked, _, _, nsent = _train_and_probe(params, xs, ys, chan)
+        new_params, stacked, stats = body(
+            params, xs, ys, chan, delayed=delayed_stack,
+            delayed_mask=delayed_mask, async_weight=aw, k_carry=k_carry)
         with jax.named_scope("aggregate"):
-            arrived = _final_arrival(scheme, chan, tau_max)
-            delayed_new = scheme.delayed_out(chan["valid"], arrived)
-            new_params, rescued = scheme.aggregate(
-                params, stacked, None, None, arrived, delayed=delayed_stack,
-                delayed_mask=delayed_mask, async_weight=aw, k_carry=k_carry)
-
             # next-round carry, padded to the fixed k_carry width
             pad = k_carry - k
             carry_stack = jax.tree_util.tree_map(
                 lambda s: jnp.pad(s, ((0, pad),) + ((0, 0),) * (s.ndim - 1)),
                 stacked)
-            carry_mask = jnp.pad(delayed_new, (0, pad))
-            dropped = chan["valid"] & ~arrived & ~rescued & ~delayed_new
-        return (new_params, carry_stack, carry_mask,
-                RoundStats(arrived, rescued, delayed_new, dropped, nsent))
+            carry_mask = jnp.pad(stats.delayed, (0, pad))
+        return new_params, carry_stack, carry_mask, stats
 
     # params + the (k_carry, ...) straggler stack/mask alias their outputs:
     # the async chain stops copying the full per-user parameter stack
     # every round
     return jax.jit(round_fn, donate_argnums=(0, 1, 2))
-
-
-def _final_arrival(scheme, chan, tau_max: float):
-    """Alg. 2 l. 14: the final upload arrives if the link is up and
-    training, the scheme's slack and the upload fit tau_max.  It reads
-    only the presampled inputs, so it is known before training."""
-    tau_f = chan["payload_bits"] / jnp.maximum(chan["final_rate"], 1e-9)
-    fits = chan["train_time"] + scheme.final_slack(chan["tau_extra0"]) \
-        + tau_f <= tau_max
-    return chan["valid"] & (~chan["final_outage"]) & fits
 
 
 def _build_streamed_round(*, scheme, local_epochs: int, lr: float,
@@ -377,18 +380,18 @@ def _build_streamed_round(*, scheme, local_epochs: int, lr: float,
     """One round that trains the selected users one after another.
 
     Every decision of the round reads presampled inputs only: the probes'
-    outcomes (``snapshot_decision`` over all users at each scheduled
-    epoch, eqs. 14-16) and the final arrival are taken before training.
-    So each user's contribution is known to be its final weights (the
-    final arrives), its last snapshot (the final is lost, a probe went
-    out) or nothing, and the epoch it is taken at is known too.  A
-    ``fori_loop`` over the selected users (the first ``sum(valid)`` slots;
-    a dynamic trip count, so one compile serves every user count and no
-    padded user trains) runs each user's epochs from the global weights
-    and, at that epoch, folds the weights into a running sum with the
-    weight the scheme's per-user ``pod_contribution`` gives them; no
-    snapshot is copied.  The round ends with the sum over its weight, or
-    the global weights where nothing arrived."""
+    outcomes (``probe`` over all users at each scheduled epoch) and the
+    final arrival are taken before training.  So each user's contribution
+    is known to be its final weights (the final arrives), its last
+    snapshot (the final is lost, a probe went out) or nothing, and the
+    epoch it is taken at is known too.  A ``fori_loop`` over the selected
+    users (the first ``sum(valid)`` slots; a dynamic trip count, so one
+    compile serves every user count and no padded user trains) runs each
+    user's epochs from the global weights and, at that epoch, folds the
+    weights into a running sum with the weight the scheme's per-user
+    ``pod_contribution`` gives them; no snapshot is copied.  The round ends
+    with the sum over its weight, or the global weights where nothing
+    arrived."""
     if scheme.carries_delayed or not scheme.per_user_fold:
         raise ValueError(
             f"scheme {scheme.name!r} aggregates all K users at once, which "
@@ -400,17 +403,19 @@ def _build_streamed_round(*, scheme, local_epochs: int, lr: float,
     def round_fn(params, xs, ys, chan):
         valid = chan["valid"]
         k = valid.shape[0]
-        arrived = _final_arrival(scheme, chan, tau_max)
+        arrived = final_arrival(
+            scheme, valid, chan["final_outage"], chan["final_rate"],
+            chan["payload_bits"], chan["train_time"], chan["tau_extra0"],
+            tau_max)
         tau_extra = chan["tau_extra0"]
         has_snap = jnp.zeros((k,), bool)
         nsent = jnp.zeros((k,), jnp.int32)
         snap_epoch = jnp.full((k,), -1, jnp.int32)
         with jax.named_scope("probe"):
             for e_t in probe_epochs:
-                tau = chan["payload_bits"] / jnp.maximum(
-                    chan["rates"][e_t - 1], 1e-9)                 # eq. 15
-                ok, tau_extra = snapshot_decision(
-                    valid, chan["outages"][e_t - 1], tau, tau_extra)
+                ok, tau_extra = probe(valid, chan["outages"][e_t - 1],
+                                      chan["rates"][e_t - 1],
+                                      chan["payload_bits"], tau_extra)
                 snap_epoch = jnp.where(ok, e_t - 1, snap_epoch)
                 has_snap = has_snap | ok
                 nsent = nsent + ok.astype(jnp.int32)
@@ -461,17 +466,15 @@ def _build_streamed_round(*, scheme, local_epochs: int, lr: float,
             denom = jnp.where(wsum > 0, wsum, 1.0)
             new_params = jax.tree_util.tree_map(
                 lambda a, p: jnp.where(wsum > 0, a / denom, p), acc, params)
-            rescued = ~arrived & has_snap
-            delayed = scheme.delayed_out(valid, arrived)
-            dropped = valid & ~arrived & ~rescued & ~delayed
-        return new_params, RoundStats(arrived, rescued, delayed, dropped,
-                                      nsent), load
+            stats = settle(scheme, valid, arrived, ~arrived & has_snap,
+                           nsent)
+        return new_params, stats, load
 
     return jax.jit(round_fn, donate_argnums=(0,))
 
 
 # ---------------------------------------------------------------------------
-# Fully on-device round: FleetState carry, channel realized in-program
+# the device driver: FleetState carry, channel realized in-program
 # ---------------------------------------------------------------------------
 
 class DeviceSimCarry(NamedTuple):
@@ -522,18 +525,15 @@ def build_device_round(*, scheme: Any, local_epochs: int,
     simulations chain under ``lax.scan`` and whole sweeps under ``vmap``
     (core/sweep.py) with zero host round trips.
 
-    ``use_codec`` (probing schemes) stores snapshots as the int8/int4
-    delta-codec state (``kernels/delta_codec``): scheduled probes quantize
-    params − round-start-global through the Pallas kernel into a
-    ``(K, M, BLOCK)`` int8 + per-block-scale carry that rides the epoch
-    ``lax.scan``, and rescues dequantize at aggregation, so a rescued
-    contribution carries true quantization noise.  ``compress_ratio``
-    (derive it from ``delta_codec.ops.codec_ratio`` when the codec is on)
-    scales the eq. 15 ``payload_bits`` — and, through them, the
-    ``select_users_jax`` latency/energy accounting, the eq. 14 τ_extra
-    budget, the final-arrival τ and the wire-byte metrics.  Everything is
-    a ``where`` over the traced ``b``/``tau_max``/``bandwidth_ratio``
-    config axes, so codec grids vmap/shard exactly like uncompressed ones.
+    ``use_codec`` (probing schemes) keeps snapshots in the delta-codec
+    format (``CodecSnapshots``), whose state rides the epoch ``lax.scan``.
+    ``compress_ratio`` (derive it from ``delta_codec.ops.codec_ratio`` when
+    the codec is on) scales the eq. 15 ``payload_bits`` — and, through
+    them, the ``select_users_jax`` latency/energy accounting, the eq. 14
+    τ_extra budget, the final-arrival τ and the wire-byte metrics.
+    Everything is a ``where`` over the traced ``b``/``tau_max``/
+    ``bandwidth_ratio`` config axes, so codec grids vmap/shard exactly like
+    uncompressed ones.
 
     Returns ``round_fn(carry, round_key, sim, cfg) -> (carry, metrics)``:
 
@@ -552,10 +552,9 @@ def build_device_round(*, scheme: Any, local_epochs: int,
     ``forward`` is a ``kernels/fused_cnn.ForwardPolicy`` (``None`` → the
     default xla/f32 policy): local training runs through its custom-VJP
     training step, in-program eval through its (value-identical) plain
-    forward.  ``codec_block`` is the quantization group width of the
-    delta-codec snapshot carry.  The returned ``round_fn`` is *unjitted* —
-    the sweep engine scans it and donates the whole ``DeviceSimCarry``
-    (params, fleet, stragglers) at its own jit boundary.
+    forward.  The returned ``round_fn`` is *unjitted* — the sweep engine
+    scans it and donates the whole ``DeviceSimCarry`` (params, fleet,
+    stragglers) at its own jit boundary.
     """
     epoch_all, fwd_eval = _resolve_epoch_fns(forward, lr, interpret)
     scheme = get_scheme(scheme)
@@ -565,7 +564,8 @@ def build_device_round(*, scheme: Any, local_epochs: int,
     # (eqs. 9–13), the eq. 14/15 τ budgets and the byte metrics alike
     eff_model_bytes = model_bytes * compress_ratio
     eff_ue_bytes = eff_model_bytes * ue_model_fraction
-    use_codec = bool(use_codec) and scheme.supports_codec
+    fmt = snapshot_format(scheme, use_codec, interpret, codec_block,
+                          codec_bits)
     K = k_select
     p = channel
 
@@ -592,9 +592,8 @@ def build_device_round(*, scheme: Any, local_epochs: int,
             / jnp.maximum(rates0[sel], 1e-9)                   # eq. (14)
 
         # -- local training: epochs in lockstep, channel drifts per epoch.
-        # Epochs run as a lax.scan (one compiled epoch body — measurably
-        # faster than the unrolled python loop on CPU and ~e× smaller to
-        # compile, which matters when a sweep compiles 3 scheme programs).
+        # Epochs run as a lax.scan, one compiled epoch body in place of e,
+        # which keeps each scheme program of a sweep small to compile.
         # Probes therefore run masked every epoch via probe_schedule_mask
         # (the schedule depends on the *traced* budget b anyway).
         stacked = jax.tree_util.tree_map(
@@ -623,26 +622,14 @@ def build_device_round(*, scheme: Any, local_epochs: int,
             if scheme.uses_probes:
                 sched = scheme.probe_schedule(e_t, local_epochs, b,
                                               override=override)
-                tau = payload_bits / jnp.maximum(rate_e, 1e-9)   # eq. (15)
-                ok, tau_extra = snapshot_decision(valid & sched, out_e,
-                                                  tau, tau_extra)
-                if use_codec:
-                    # the snapshot carry is the int8 payload itself, so the
-                    # epoch scan carries ~4x fewer snapshot bytes and the
-                    # rescue later decodes with true quantization noise
-                    q_new, s_new = _codec_encode(stacked, params, interpret,
-                                                 codec_block, codec_bits)
-                    snap = (jnp.where(_kx(ok, q_new), q_new, snap[0]),
-                            jnp.where(_kx(ok, s_new), s_new, snap[1]))
-                else:
-                    snap = _tree_where_k(ok, stacked, snap)
+                ok, tau_extra = probe(valid & sched, out_e, rate_e,
+                                      payload_bits, tau_extra)
+                snap = fmt.take(ok, stacked, params, snap)
                 has_snap = has_snap | ok
                 nsent = nsent + ok.astype(jnp.int32)
             return (fleet, stacked, snap, has_snap, nsent, tau_extra), ()
 
-        snap0 = (_codec_zero_state(stacked, codec_block) if use_codec
-                 else stacked)
-        carry_e = (fleet, stacked, snap0, jnp.zeros((K,), bool),
+        carry_e = (fleet, stacked, fmt.empty(stacked), jnp.zeros((K,), bool),
                    jnp.zeros((K,), jnp.int32), tau_extra0)
         carry_e, _ = jax.lax.scan(epoch_body, carry_e,
                                   jnp.arange(1, local_epochs + 1))
@@ -651,22 +638,19 @@ def build_device_round(*, scheme: Any, local_epochs: int,
         # -- final upload (Alg. 2 l. 14): no extra move -----------------------
         rate_f = fleet_rates(fleet, p, bw)[sel]
         fleet, bad_f = fleet_outage_step(fleet, p)
-        tau_f = payload_bits / jnp.maximum(rate_f, 1e-9)
-        fits = train_time + scheme.final_slack(tau_extra0) + tau_f <= tau_max
-        arrived = valid & (~bad_f[sel]) & fits
+        arrived = final_arrival(scheme, valid, bad_f[sel], rate_f,
+                                payload_bits, train_time, tau_extra0,
+                                tau_max)
 
         # -- aggregation (registry dispatch — no scheme branches) -------------
-        if use_codec:
-            snap = _codec_decode(snap[0], snap[1], stacked, params,
-                                 interpret)
         new_params, rescued = scheme.aggregate(
-            params, stacked, snap, has_snap, arrived, delayed=carry.delayed,
-            delayed_mask=carry.delayed_mask, async_weight=aw, k_carry=K)
-        delayed_new = scheme.delayed_out(valid, arrived)
-        dropped = valid & ~arrived & ~rescued & ~delayed_new
+            params, stacked, fmt.restore(snap, stacked, params), has_snap,
+            arrived, delayed=carry.delayed, delayed_mask=carry.delayed_mask,
+            async_weight=aw, k_carry=K)
+        stats = settle(scheme, valid, arrived, rescued, nsent)
         if scheme.carries_delayed:
             new_carry = DeviceSimCarry(new_params, fleet, stacked,
-                                       delayed_new)
+                                       stats.delayed)
         else:
             new_carry = DeviceSimCarry(new_params, fleet, carry.delayed,
                                        carry.delayed_mask)
@@ -683,8 +667,8 @@ def build_device_round(*, scheme: Any, local_epochs: int,
             selected=n_taken,
             arrived=jnp.sum(arrived.astype(jnp.int32)),
             rescued=jnp.sum(rescued.astype(jnp.int32)),
-            delayed=jnp.sum(delayed_new.astype(jnp.int32)),
-            dropped=jnp.sum(dropped.astype(jnp.int32)),
+            delayed=jnp.sum(stats.delayed.astype(jnp.int32)),
+            dropped=jnp.sum(stats.dropped.astype(jnp.int32)),
             bytes_sent=bytes_sent.astype(jnp.float32),
             test_loss=cross_entropy(logits, sim["test_y"]),
             test_acc=accuracy(logits, sim["test_y"]))

@@ -21,8 +21,8 @@ Two round engines share the control plane:
   fused (default) — ``core/fused_round``: channel + batches presampled
       host-side once per round, then the whole round (vmapped users, scanned
       epochs, on-device OPT scheduler, masked-mean aggregation) runs as one
-      jitted device program.  ~5x faster at fig3 scale; optional int8
-      delta-codec snapshots (``use_delta_codec``).
+      jitted device program; optional int8 delta-codec snapshots
+      (``use_delta_codec``).
   host — the original Python control loop over ``OppTransmitter``; kept as
       the reference implementation.  ``tests/test_fused_round.py`` pins the
       two to identical per-round arrived/rescued/dropped trajectories.

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.schemes import get_scheme
+from repro.core.schemes import get_scheme, probe
 from repro.core.transmission import schedule_period as _schedule_period
 from repro.models import module as m
 from repro.training.train_state import TrainState
@@ -66,29 +66,12 @@ def is_scheduled(cfg: OppSyncConfig, inner_step: jnp.ndarray) -> jnp.ndarray:
         & (inner_step > 0)
 
 
-def snapshot_decision(scheduled: jnp.ndarray, outage: jnp.ndarray,
-                      tau: jnp.ndarray, tau_extra: jnp.ndarray
-                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Alg. 2 lines 17–21 decision core, branch-free and shape-polymorphic.
-
-    Shared single source of truth between the multi-pod OppSync feature
-    (scalar per-pod state under shard_map) and the fused HSFL round
-    ((K,)-vectors over the stacked-user axis): a scheduled probe succeeds
-    iff the channel is up and the instantaneous delay τ (eq. 15) fits the
-    remaining allowance; success burns τ from the budget (eq. 16).
-    Returns (ok, new_tau_extra).
-    """
-    ok = scheduled & (~outage) & (tau <= tau_extra)
-    return ok, jnp.where(ok, tau_extra - tau, tau_extra)
-
-
 def maybe_snapshot(cfg: OppSyncConfig, state: TrainState,
                    rate: jnp.ndarray, outage: jnp.ndarray) -> TrainState:
     """Opportunistic_Transmission (Alg. 2 lines 17–21), branch-free."""
     inner = state.step % cfg.inner_steps
-    tau = cfg.payload / jnp.maximum(rate, 1e-9)              # eq. (15)
-    ok, tau_extra = snapshot_decision(is_scheduled(cfg, inner), outage,
-                                      tau, state.tau_extra)
+    ok, tau_extra = probe(is_scheduled(cfg, inner), outage, rate,
+                          cfg.payload, state.tau_extra)
     snapshot = m.tree_where(ok, state.params, state.snapshot)
     return state._replace(
         snapshot=snapshot,

@@ -221,6 +221,20 @@ def probe_schedule_mask(e_t: int, local_epochs: int, b) -> jnp.ndarray:
             & (et <= (bf - 1.0) * period))
 
 
+def probe(scheduled, outage, rate, payload_bits, tau_extra
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One opportunistic probe (Alg. 2 l. 12–21), branch-free and
+    shape-polymorphic: a scheduled probe succeeds iff the channel is up and
+    the upload delay τ at ``rate`` (eq. 15) fits the remaining allowance;
+    success burns τ from the budget (eq. 16).  Returns (ok, new_tau_extra).
+    The one probe of every engine: per pod under shard_map
+    (``opportunistic_sync``) and over the (K,) user axis of the stacked,
+    streamed and device rounds (``fused_round``)."""
+    tau = payload_bits / jnp.maximum(rate, 1e-9)             # eq. (15)
+    ok = scheduled & (~outage) & (tau <= tau_extra)
+    return ok, jnp.where(ok, tau_extra - tau, tau_extra)
+
+
 # ---------------------------------------------------------------------------
 # the Scheme protocol
 # ---------------------------------------------------------------------------
